@@ -1,73 +1,21 @@
-"""Version compatibility shims for jax API drift.
+"""Mesh construction with the axis semantics this repo is written for.
 
-The repo targets jax 0.4.37 (the container's pinned toolchain) but is
-written against the newer public names where they exist, so everything
-that moved between 0.4.x and 0.5+/0.6+ is funneled through here:
-
-* ``shard_map``      — ``jax.shard_map`` (new) vs
-                       ``jax.experimental.shard_map.shard_map`` (0.4.x).
-* ``make_mesh``      — ``jax.make_mesh`` grew an ``axis_types=`` kwarg
-                       after 0.4.37; we pass it only when supported.
-* ``set_mesh``       — ``jax.set_mesh(mesh)`` context manager (new); on
-                       0.4.x the ``Mesh`` object itself is the context
-                       manager.
-* ``cost_analysis``  — ``Compiled.cost_analysis()`` returned a
-                       one-element list on some 0.4.x versions and a dict
-                       on newer ones.
+``jax.make_mesh`` gives new meshes Explicit axis types; every sharding
+rule here (``parallel/sharding.py``, the data-parallel plans, the MoE
+dispatch) assumes Auto axes, where the compiler propagates shardings.
 """
 
 from __future__ import annotations
 
-import inspect
 from typing import Sequence
 
 import jax
 
-__all__ = ["shard_map", "make_mesh", "set_mesh", "cost_analysis"]
-
-
-try:  # jax >= 0.6 style
-    from jax import shard_map as _shard_map  # type: ignore[attr-defined]
-
-    _CHECK_KW = "check_vma"
-except ImportError:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _CHECK_KW = "check_rep"
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None):
-    """``shard_map`` with the replication-check kwarg spelled per-version
-    (``check_vma`` new, ``check_rep`` on 0.4.x)."""
-    kwargs = {} if check_vma is None else {_CHECK_KW: check_vma}
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **kwargs)
-
-
-_MAKE_MESH_HAS_AXIS_TYPES = (
-    "axis_types" in inspect.signature(jax.make_mesh).parameters
-)
+__all__ = ["make_mesh"]
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str]):
-    """``jax.make_mesh`` with Auto axis types where the kwarg exists."""
-    if _MAKE_MESH_HAS_AXIS_TYPES:
-        return jax.make_mesh(
-            tuple(axis_shapes), tuple(axis_names),
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names))
-
-
-def set_mesh(mesh):
-    """Context manager installing ``mesh`` as the ambient mesh."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh  # 0.4.x: Mesh is its own context manager
-
-
-def cost_analysis(compiled) -> dict:
-    """Normalized ``Compiled.cost_analysis()``: always a flat dict."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0]
-    return ca
+    """``jax.make_mesh`` with Auto axis types."""
+    return jax.make_mesh(
+        tuple(axis_shapes), tuple(axis_names),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))

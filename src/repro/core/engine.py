@@ -854,10 +854,10 @@ def _data_parallel_plan(qnet, input_shape, method, data_parallel, spec=None,
     # the per-shard skip counters — each shard ran its own prepass); no
     # collectives cross shards, so replication checking is moot (and trips
     # over pallas_call on some jax versions) -> disabled.
-    fn = compat.shard_map(inner._fn, mesh=mesh,
-                          in_specs=(P(), P("batch")),
-                          out_specs=(P("batch"), P("batch")),
-                          check_vma=False)
+    fn = jax.shard_map(inner._fn, mesh=mesh,
+                      in_specs=(P(), P("batch")),
+                      out_specs=(P("batch"), P("batch")),
+                      check_vma=False)
     infos = [dataclasses.replace(
         l,
         out_shape=(l.out_shape[0] * data_parallel,) + l.out_shape[1:],

@@ -7,7 +7,9 @@ same reason).  Which execution strategy is native differs per backend:
 
 * **TPU** — the Pallas kernels with ``mxu_dtype="int8"`` (int8 operands,
   ``preferred_element_type=int32``): one MXU pass per plane at the int8
-  systolic rate, tile shapes sized to VMEM.
+  systolic rate, tile shapes sized to VMEM.  This is also the untuned
+  default: the TPU compiler refuses an int32 x int32 dot, so no Pallas
+  kernel emits one.
 * **CPU CI** — Pallas runs in interpret mode, and XLA:CPU has no VNNI /
   AMX matmul lowering (integer ``dot_general`` falls back to scalar
   loops, ~6x slower than the BLAS float path).  Here the winner is the
@@ -63,7 +65,7 @@ __all__ = [
     "cache_path",
 ]
 
-MXU_DTYPES = ("int32", "int8", "f32")
+MXU_DTYPES = ("int8", "f32")
 ACT_DTYPES = ("u8", "f32")       # activation layout at the layer boundary
 _F32_MANTISSA = 1 << 24          # f32 sums of integers are exact below this
 _WEIGHT_MAX = 127                # int8 weight magnitude bound
@@ -78,9 +80,9 @@ class KernelConfig:
     ``impl="xla"`` runs the jitted XLA twin of the same plane-pass math
     (no tiling — XLA picks its own blocking).  ``mxu_dtype`` selects the
     per-plane ``dot_general`` lowering: ``"int8"`` (operands cast to
-    int8, ``preferred_element_type=int32`` — the TPU MXU-native path),
-    ``"f32"`` (BLAS-rate float dots, exact under :func:`exact_lowering`)
-    or ``"int32"`` (the always-exact reference lowering).
+    int8, ``preferred_element_type=int32`` — the TPU MXU-native path and
+    the default; always exact, see :func:`exact_lowering`) or ``"f32"``
+    (BLAS-rate float dots, exact under :func:`exact_lowering`).
     ``plane_parallel`` moves the bitserial plane loop into its own grid
     dimension under weight-stationary block specs (Pallas only): the
     weight tile's index map is independent of the plane index, so one
@@ -102,7 +104,7 @@ class KernelConfig:
     """
 
     impl: str = "pallas"              # "pallas" | "xla"
-    mxu_dtype: str = "int32"          # per-plane dot lowering
+    mxu_dtype: str = "int8"           # per-plane dot lowering
     bm: int = 128                     # matmul M tile (pallas)
     bk: int = 128                     # matmul K tile (pallas)
     bn: int = 128                     # matmul N tile (pallas)
@@ -144,26 +146,25 @@ def exact_lowering(
     k_contract: int,
     method: str,
 ) -> bool:
-    """True iff ``mxu_dtype`` reproduces the int32 accumulation bit-exactly.
+    """True iff ``mxu_dtype`` reproduces the integer accumulation exactly.
 
     ``max_operand`` is the largest activation value a dot can see
     (``2^T - 1`` for the fused packed pass, 1 for a bitserial plane
     pass), ``k_contract`` the total contraction length of one layer
     (``K`` for matmuls, ``kh * kw * Cin`` for convs).
 
-    * ``int32`` — always exact (the reference lowering).
-    * ``int8``  — exact iff both operands fit int8: weights are int8 by
-      construction, so the bound is ``max_operand <= 127`` (always true
-      for bitserial plane bits; true for fused iff ``T <= 7``).
-    * ``f32``   — products and partial sums are integers computed in
+    * ``int8`` — always exact: int8 x int8 -> int32 MXU passes.  Weights
+      and plane bits fit int8 as they are; packed levels fit while
+      ``T <= 7``, and a wider operand (``T >= 8``, a sum-pool carry) is
+      split into 7-bit slices, one pass each, shifted back in int32
+      (``radix_matmul.int8_contract``).  It is the default lowering.
+    * ``f32``  — products and partial sums are integers computed in
       f32; exact while every partial sum stays below the 24-bit
       mantissa.  One headroom bit is reserved for the epilogue bias add.
     """
-    if mxu_dtype == "int32":
+    if mxu_dtype == "int8":
         return True
     operand = 1 if method == "bitserial" else max_operand
-    if mxu_dtype == "int8":
-        return operand <= 127
     if mxu_dtype == "f32":
         return operand * _WEIGHT_MAX * k_contract <= _F32_MANTISSA // 2
     raise ValueError(mxu_dtype)
@@ -273,7 +274,7 @@ def matmul_candidates(
 ) -> List[KernelConfig]:
     """Legal strategies for one matmul problem, heuristic-first.
 
-    The first candidate is always today's default (Pallas, int32
+    The first candidate is always the untuned default (Pallas, int8
     lowering, heuristic 128 tiles) so an interrupted or budget-capped
     sweep can never regress below the untuned path.  On the interpret
     backend (CPU) the sweep leans on the XLA twin + full-dim tiles —
@@ -343,9 +344,10 @@ def _attn_dtype_options(num_steps: int, q_bits: int, hd: int,
 
     Both operands are activations here (query levels <= 2^q_bits - 1,
     key levels <= 2^T - 1 fused / plane bits bitserial), so the gate runs
-    on the larger of the two — ``exact_lowering``'s int8 bound then
-    requires both to fit, and its f32 mantissa bound stays conservative
-    (the 127 weight factor dominates the true smaller operand)."""
+    on the larger of the two — int8 is always exact (the kernel slices
+    an operand wider than int8), and the f32 mantissa bound stays
+    conservative (the 127 weight factor dominates the true smaller
+    operand)."""
     qlvl = (1 << q_bits) - 1
     lvl = (1 << num_steps) - 1
     operand = qlvl if dataflow == "bitserial" else max(qlvl, lvl)
@@ -421,6 +423,8 @@ class AutotuneStats:
     misses: int = 0       # key not in the process table
     sweeps: int = 0       # full candidate sweeps actually timed
     disk_hits: int = 0    # misses resolved from the on-disk table
+    skipped: int = 0      # candidates whose build or run raised
+    first_error: Optional[str] = None   # the first such error, verbatim
 
     def as_dict(self) -> dict:
         return dataclasses.asdict(self)
@@ -557,8 +561,10 @@ def tune(
     ``build(config)`` returns a zero-arg thunk executing the strategy on
     representative inputs; ``timer`` (injectable — tests pass a fake)
     maps a thunk to microseconds, defaulting to :func:`measure`.  A
-    candidate whose build or execution raises is skipped (e.g. a tile
-    shape the backend rejects); the winner is the minimum time with
+    candidate whose build or execution raises (e.g. a tile shape or a
+    lowering the backend rejects) is skipped, but never silently: it
+    counts in ``cache.stats.skipped`` and the first error is kept in
+    ``cache.stats.first_error``.  The winner is the minimum time with
     ties broken by candidate order, which makes selection deterministic
     under any injected timer.  The winner is cached (process + disk).
     """
@@ -575,13 +581,18 @@ def tune(
         try:
             thunk = build(cand)
             us = float(timer(thunk))
-        except Exception:
-            continue                  # illegal strategy for this problem
+        except Exception as err:      # illegal strategy for this problem
+            cache.stats.skipped += 1
+            if cache.stats.first_error is None:
+                cache.stats.first_error = (
+                    f"{cand}: {type(err).__name__}: {err}")
+            continue
         if best is None or (us, idx) < (best[0], best[1]):
             best = (us, idx, cand)
     cache.stats.sweeps += 1
     if best is None:
         raise RuntimeError(
-            f"autotune: every candidate failed for key {key}")
+            f"autotune: every candidate failed for key {key}; first "
+            f"error: {cache.stats.first_error}")
     cache.put(key, best[2], best[0])
     return best[2]

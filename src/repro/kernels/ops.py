@@ -46,6 +46,7 @@ from repro.kernels.radix_matmul import (
     OCC_LANES,
     _project_levels,
     gated,
+    int8_contract,
     mxu_dot,
     occ_mask,
     radix_matmul_pallas,
@@ -66,6 +67,11 @@ __all__ = [
 
 
 def _interpret() -> bool:
+    """Interpret mode exactly when JAX's default backend is the CPU: the
+    kernels compile for whatever accelerator JAX found, and run in Python
+    only on a host that has none.  A run meant for the chip must check
+    the platform itself (``chip_smoke.py`` refuses anything but a TPU),
+    since JAX falls back to the CPU when it finds no accelerator."""
     return jax.default_backend() == "cpu"
 
 
@@ -178,7 +184,7 @@ def epilogue_rows(
     static_argnames=("num_steps", "method", "periods", "mxu_dtype",
                      "out_level", "out_grid", "acc_dtype"))
 def _xla_matmul(x2, w2, bias, mult, occ, *, num_steps, method, periods=1,
-                mxu_dtype="int32", out_level=None, out_grid="dense",
+                mxu_dtype="int8", out_level=None, out_grid="dense",
                 acc_dtype="int32"):
     """Jitted XLA twin of ``radix_matmul_pallas`` (unpadded shapes)."""
     # ``mxu_dot`` lowers both operands itself, so the packed input and the
@@ -193,7 +199,7 @@ def _xla_matmul(x2, w2, bias, mult, occ, *, num_steps, method, periods=1,
         x = x2
         if occ_row is not None:
             x = x.astype(jnp.int32) & occ_mask(occ_row, num_steps)
-        acc = mxu_dot(x, w, mxu_dtype, acc_dtype)
+        acc = mxu_dot(x, w, mxu_dtype, acc_dtype, a_bits=num_steps)
     else:
         x = x2.astype(jnp.int32)
         zero = jnp.zeros((x.shape[0], w.shape[1]), jnp.int32)
@@ -218,24 +224,24 @@ def _xla_matmul(x2, w2, bias, mult, occ, *, num_steps, method, periods=1,
     return _project_levels(q, out_level=out_level, out_grid=out_grid)
 
 
-def _conv_lowered(p, w, stride, mxu_dtype, acc_dtype="int32"):
+def _conv_lowered(p, w, stride, mxu_dtype, acc_dtype="int32", a_bits=None):
     """One plane/packed conv under the selected lowering.  int32 out,
     except ``acc_dtype="f32"`` (the f32 boundary layout) keeps the
-    exact-integer f32 accumulator — same contract as ``mxu_dot``."""
+    exact-integer f32 accumulator — same contract as ``mxu_dot``, whose
+    ``a_bits`` operand bound it shares."""
+    def conv(a, b, pet):
+        return jax.lax.conv_general_dilated(
+            a, b, window_strides=(stride, stride), padding="VALID",
+            dimension_numbers=("NHWC", "HWIO", "NHWC"),
+            preferred_element_type=pet)
+
     if mxu_dtype == "int8":
-        p, w, pet = p.astype(jnp.int8), w.astype(jnp.int8), jnp.int32
-    elif mxu_dtype == "f32":
-        p, w, pet = (p.astype(jnp.float32), w.astype(jnp.float32),
-                     jnp.float32)
-    else:
-        p, w, pet = p.astype(jnp.int32), w.astype(jnp.int32), jnp.int32
-    out = jax.lax.conv_general_dilated(
-        p, w, window_strides=(stride, stride), padding="VALID",
-        dimension_numbers=("NHWC", "HWIO", "NHWC"),
-        preferred_element_type=pet)
-    if acc_dtype == "f32" and mxu_dtype == "f32":
-        return out
-    return out.astype(jnp.int32)
+        return int8_contract(lambda a, b: conv(a, b, jnp.int32), p, w,
+                             a_bits=a_bits)
+    if mxu_dtype != "f32":
+        raise ValueError(f"unknown mxu_dtype {mxu_dtype!r}")
+    out = conv(p.astype(jnp.float32), w.astype(jnp.float32), jnp.float32)
+    return out if acc_dtype == "f32" else out.astype(jnp.int32)
 
 
 @functools.partial(
@@ -243,7 +249,7 @@ def _conv_lowered(p, w, stride, mxu_dtype, acc_dtype="int32"):
     static_argnames=("num_steps", "method", "stride", "periods", "mxu_dtype",
                      "out_level", "out_grid", "acc_dtype"))
 def _xla_conv2d(x_q, w_q, bias, mult, occ, *, num_steps, method, stride=1,
-                periods=1, mxu_dtype="int32", out_level=None,
+                periods=1, mxu_dtype="int8", out_level=None,
                 out_grid="dense", acc_dtype="int32"):
     """Jitted XLA twin of ``radix_conv2d_pallas`` (VALID, pre-padded)."""
     # same operand-lowering contract as ``_xla_matmul``: ``_conv_lowered``
@@ -254,7 +260,8 @@ def _xla_conv2d(x_q, w_q, bias, mult, occ, *, num_steps, method, stride=1,
         x = x_q
         if occ_row is not None:
             x = x.astype(jnp.int32) & occ_mask(occ_row, num_steps)
-        acc = _conv_lowered(x, w, stride, mxu_dtype, acc_dtype)
+        acc = _conv_lowered(x, w, stride, mxu_dtype, acc_dtype,
+                            a_bits=num_steps)
     else:
         x = x_q.astype(jnp.int32)
         h_out = (x.shape[1] - w.shape[0]) // stride + 1
@@ -520,22 +527,19 @@ def radix_conv2d(
 # ---------------------------------------------------------------------------
 
 
-def _attn_bdot(a, b, mxu_dtype):
+def _attn_bdot(a, b, mxu_dtype, *, a_bits=None, b_bits=None):
     """(N, g, d) x (N, blk, d) -> (N, g, blk) int32 batched contraction
     under the selected lowering (``mxu_dot``'s contract, batched)."""
     dn = (((2,), (2,)), ((0,), (0,)))
     if mxu_dtype == "int8":
-        return jax.lax.dot_general(
-            a.astype(jnp.int8), b.astype(jnp.int8), dn,
-            preferred_element_type=jnp.int32)
+        return int8_contract(
+            lambda x, y: jax.lax.dot_general(
+                x, y, dn, preferred_element_type=jnp.int32),
+            a, b, a_bits=a_bits, b_bits=b_bits)
     if mxu_dtype == "f32":
         return jax.lax.dot_general(
             a.astype(jnp.float32), b.astype(jnp.float32), dn,
             preferred_element_type=jnp.float32).astype(jnp.int32)
-    if mxu_dtype == "int32":
-        return jax.lax.dot_general(
-            a.astype(jnp.int32), b.astype(jnp.int32), dn,
-            preferred_element_type=jnp.int32)
     raise ValueError(f"unknown mxu_dtype {mxu_dtype!r}")
 
 
@@ -552,7 +556,7 @@ def _attn_bdot_f32(p, v):
                      "blk", "mxu_dtype", "sparsity"))
 def _xla_decode_attn(qq, qs, kq, ks, vq, vs, mask, occ_k, occ_v, *,
                      num_steps, q_bits, hd, method, packed, blk,
-                     mxu_dtype="int32", sparsity=True):
+                     mxu_dtype="int8", sparsity=True):
     """Jitted XLA twin of ``radix_decode_attn_pallas`` (same (N = B*Hkv)
     row layout, S pre-padded to a ``blk`` multiple).  Processes the cache
     blockwise through the shared online-softmax core — only the current
@@ -576,7 +580,8 @@ def _xla_decode_attn(qq, qs, kq, ks, vq, vs, mask, occ_k, occ_v, *,
 
         if method == "fused":
             kb_m = kb if occk is None else kb & occ_mask(occk, num_steps)
-            sint = _attn_bdot(qq, kb_m, mxu_dtype)
+            sint = _attn_bdot(qq, kb_m, mxu_dtype, a_bits=q_bits,
+                              b_bits=num_steps)
         else:
             zero = jnp.zeros((n, g, kb.shape[1]), jnp.int32)
             sint = zero
@@ -584,7 +589,8 @@ def _xla_decode_attn(qq, qs, kq, ks, vq, vs, mask, occ_k, occ_v, *,
                 plane = (kb >> s) & 1
                 sint = sint + (gated(
                     occk, s,
-                    lambda plane=plane: _attn_bdot(qq, plane, mxu_dtype),
+                    lambda plane=plane: _attn_bdot(qq, plane, mxu_dtype,
+                                                   a_bits=q_bits),
                     zero) << s)
         ksum = jnp.sum(kb, axis=-1)[:, None, :]           # (n, 1, blk)
         scores = radix_attn.plane_scores(

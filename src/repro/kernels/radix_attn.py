@@ -65,7 +65,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.radix_matmul import OCC_LANES, gated, occ_mask
+from repro.kernels.radix_matmul import (
+    OCC_LANES,
+    gated,
+    int8_contract,
+    occ_mask,
+)
 
 __all__ = [
     "Q_BITS",
@@ -173,23 +178,21 @@ def osm_finalize(state):
 # ---------------------------------------------------------------------------
 
 
-def _dot_nt(a, b, mxu_dtype: str) -> jax.Array:
+def _dot_nt(a, b, mxu_dtype: str, *, a_bits=None, b_bits=None) -> jax.Array:
     """(g, d) x (blk, d) -> (g, blk) int32, contracting the shared last
     dim — ``mxu_dot``'s lowering contract for the transposed-operand
-    layout attention uses (K arrives token-major)."""
+    layout attention uses (K arrives token-major).  ``a_bits``/``b_bits``
+    bound the non-negative level operands for the int8 lowering."""
     dn = (((1,), (1,)), ((), ()))
     if mxu_dtype == "int8":
-        return jax.lax.dot_general(
-            a.astype(jnp.int8), b.astype(jnp.int8), dn,
-            preferred_element_type=jnp.int32)
+        return int8_contract(
+            lambda x, y: jax.lax.dot_general(
+                x, y, dn, preferred_element_type=jnp.int32),
+            a, b, a_bits=a_bits, b_bits=b_bits)
     if mxu_dtype == "f32":
         return jax.lax.dot_general(
             a.astype(jnp.float32), b.astype(jnp.float32), dn,
             preferred_element_type=jnp.float32).astype(jnp.int32)
-    if mxu_dtype == "int32":
-        return jax.lax.dot_general(
-            a.astype(jnp.int32), b.astype(jnp.int32), dn,
-            preferred_element_type=jnp.int32)
     raise ValueError(f"unknown mxu_dtype {mxu_dtype!r}")
 
 
@@ -211,7 +214,7 @@ def unpack_levels(x, packed: bool) -> jax.Array:
     return jnp.concatenate([(xi >> 4) & 0xF, xi & 0xF], axis=-1)
 
 
-def _qk_tile(qq, kq, occ, *, num_steps: int, method: str,
+def _qk_tile(qq, kq, occ, *, num_steps: int, q_bits: int, method: str,
              mxu_dtype: str) -> jax.Array:
     """<qq, qk> integer tile: fused single pass over packed levels, or
     bit-serial plane passes — each gated behind the occupancy prepass so
@@ -220,13 +223,14 @@ def _qk_tile(qq, kq, occ, *, num_steps: int, method: str,
     identity on real data)."""
     if method == "fused":
         kq_m = kq if occ is None else kq & occ_mask(occ, num_steps)
-        return _dot_nt(qq, kq_m, mxu_dtype)
+        return _dot_nt(qq, kq_m, mxu_dtype, a_bits=q_bits, b_bits=num_steps)
     zero = jnp.zeros((qq.shape[0], kq.shape[0]), jnp.int32)
     sint = zero
     for s in range(num_steps):
         plane = (kq >> s) & 1
         sint = sint + (gated(
-            occ, s, lambda plane=plane: _dot_nt(qq, plane, mxu_dtype),
+            occ, s,
+            lambda plane=plane: _dot_nt(qq, plane, mxu_dtype, a_bits=q_bits),
             zero) << s)
     return sint
 
@@ -265,8 +269,9 @@ def radix_decode_attn_kernel(
     Grid dim 0 walks the B*Hkv rows, dim 1 the KV blocks (innermost, so
     the (m, l, acc) VMEM scratch carries the online-softmax state across
     the whole cache for one row).  Block shapes: qq (1, g, hd) int32
-    levels, kq/vq (1, blk, hd or hd//2) uint8, ks/vs/mask (1, blk),
-    occ (1, OCC_LANES)."""
+    levels, qs (1, g, 1), kq/vq (1, blk, hd or hd//2) uint8, ks/vs/mask
+    (1, 1, blk), occ (1, OCC_LANES) — the last two dims of every block
+    are lane/sublane aligned or span the array, as the TPU requires."""
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -276,17 +281,17 @@ def radix_decode_attn_kernel(
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     qq = qq_ref[0]                                     # (g, hd) int32
-    qs = qs_ref[0][:, None]                            # (g, 1) f32
+    qs = qs_ref[0]                                     # (g, 1) f32
     kq = unpack_levels(kq_ref[0], packed)              # (blk, hd) int32
     vq = unpack_levels(vq_ref[0], packed)
-    sk = ks_ref[0][None, :]                            # (1, blk) f32
-    sv = vs_ref[0][None, :]
-    mask = (mask_ref[0] > 0)[None, :]                  # (1, blk) bool
+    sk = ks_ref[0]                                     # (1, blk) f32
+    sv = vs_ref[0]
+    mask = mask_ref[0] > 0                             # (1, blk) bool
     occk = occk_ref[0] if sparsity else None
     occv = occv_ref[0] if sparsity else None
 
-    sint = _qk_tile(qq, kq, occk, num_steps=num_steps, method=method,
-                    mxu_dtype=mxu_dtype)
+    sint = _qk_tile(qq, kq, occk, num_steps=num_steps, q_bits=q_bits,
+                    method=method, mxu_dtype=mxu_dtype)
     qsum = jnp.sum(qq, axis=-1, keepdims=True)         # (g, 1) int32
     ksum = jnp.sum(kq, axis=-1)[None, :]               # (1, blk) int32
     scores = plane_scores(sint, qsum, ksum, qs, sk, hd=hd,
@@ -329,7 +334,7 @@ def radix_decode_attn_pallas(
     method: str = "bitserial",
     packed: bool = False,
     blk: int = 128,
-    mxu_dtype: str = "int32",
+    mxu_dtype: str = "int8",
     sparsity: bool = True,
     interpret: bool = False,
 ) -> jax.Array:
@@ -361,12 +366,12 @@ def radix_decode_attn_pallas(
         grid=(n, nj),
         in_specs=[
             pl.BlockSpec((1, g, hdq), lambda n_, j_: (n_, 0, 0)),      # qq
-            pl.BlockSpec((1, g), lambda n_, j_: (n_, 0)),              # qs
+            pl.BlockSpec((1, g, 1), lambda n_, j_: (n_, 0, 0)),        # qs
             pl.BlockSpec((1, blk, hdp), lambda n_, j_: (n_, j_, 0)),   # kq
-            pl.BlockSpec((1, blk), lambda n_, j_: (n_, j_)),           # ks
+            pl.BlockSpec((1, 1, blk), lambda n_, j_: (n_, 0, j_)),     # ks
             pl.BlockSpec((1, blk, hdp), lambda n_, j_: (n_, j_, 0)),   # vq
-            pl.BlockSpec((1, blk), lambda n_, j_: (n_, j_)),           # vs
-            pl.BlockSpec((1, blk), lambda n_, j_: (n_, j_)),           # mask
+            pl.BlockSpec((1, 1, blk), lambda n_, j_: (n_, 0, j_)),     # vs
+            pl.BlockSpec((1, 1, blk), lambda n_, j_: (n_, 0, j_)),     # mask
             pl.BlockSpec((1, OCC_LANES), lambda n_, j_: (0, 0)),       # occ_k
             pl.BlockSpec((1, OCC_LANES), lambda n_, j_: (0, 0)),       # occ_v
         ],
@@ -378,6 +383,7 @@ def radix_decode_attn_pallas(
             pltpu.VMEM((g, hdq), jnp.float32),    # output accumulator
         ],
         interpret=interpret,
-    )(qq, qs, kq.astype(jnp.uint8), ks, vq.astype(jnp.uint8), vs,
-      mask.astype(jnp.int32), occ_k.astype(jnp.int32),
+    )(qq, qs[:, :, None], kq.astype(jnp.uint8), ks[:, None, :],
+      vq.astype(jnp.uint8), vs[:, None, :],
+      mask.astype(jnp.int32)[:, None, :], occ_k.astype(jnp.int32),
       occ_v.astype(jnp.int32))

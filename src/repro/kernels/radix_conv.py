@@ -5,10 +5,11 @@ TPU adaptation of the paper's convolution unit (Fig. 2):
 * FPGA: an input *row* lives in a shift register; kernel rows stream through
   a Y x X adder array; partial sums propagate down; time steps Horner-merge
   in the output logic.
-* TPU: an input *row block* (all W positions, all input channels, whole
-  T-packed byte per activation) lives in VMEM; the kernel-row/column loops
-  are static unrolls around MXU matmuls over the input-channel dim; time
-  steps Horner-merge in an int32 register tile.
+* TPU: a *band* of input rows (the output row tile plus its ``kh - stride``
+  row halo, all W positions, all input channels, whole T-packed byte per
+  activation) lives in VMEM; the kernel-row/column loops are static
+  unrolls around MXU matmuls over the input-channel dim; time steps
+  Horner-merge in an int32 register tile.
 
 Strided convolutions subsample *inside* the kernel: each (kh, kw) tap
 gathers only the rows/columns that land on the stride grid, so the kernel
@@ -30,10 +31,13 @@ int32 register tile before the store, emitting packed uint8 levels — the
 raw accumulator never reaches HBM.  Without ``mult`` the kernel emits
 int32 accumulators (logits-layer path).
 
-Grid: (batch, C_out blocks).  VALID convs (ops.py pre-pads SAME).  The halo
-(kernel_h - 1 rows) is handled by passing the full H dimension per block and
-slicing rows inside the kernel, which is exact for these feature-map sizes
-(<= 224 rows -> <= 3.2 MB VMEM per block at VGG scale).
+Grid: (batch, C_out blocks, output-row tiles[, plane]).  VALID convs
+(ops.py pre-pads SAME).  Each step reads an element-indexed band of
+``(bh - 1) * stride + kh`` input rows starting at ``r * bh * stride``, so
+neighbouring bands overlap by the halo and VMEM holds one band, never the
+whole image: :func:`row_tile` keeps a tile's matmul rows (``bh * w_out``)
+at or under ``TILE_ROWS``.  That is what fits VGG-11's 224x224 layers
+(conv1 226x226x3, conv2 114x114x64) on a v5e.
 """
 
 from __future__ import annotations
@@ -54,52 +58,58 @@ from repro.kernels.radix_matmul import (
     occ_mask,
 )
 
-__all__ = [
-    "radix_conv2d_kernel",
-    "radix_conv2d_epilogue_kernel",
-    "radix_conv2d_pallas",
-]
+__all__ = ["TILE_ROWS", "row_tile", "radix_conv2d_pallas"]
+
+TILE_ROWS = 2048
+"""Upper bound on one grid step's matmul rows (``bh * w_out``): the int32
+accumulator tile is at most ``TILE_ROWS x bco x 4`` bytes of VMEM."""
 
 
-def _conv_acc(x, w_ref, h_out, w_out, bco, *, num_steps, method, kh, kw,
-              stride, periods=1, occ=None, mxu_dtype="int32"):
-    """Strided VALID conv of an (H, W, Cin) int32 block -> (h_out*w_out, bco).
+def row_tile(h_out: int, w_out: int) -> int:
+    """Output rows per grid step: the largest divisor of ``h_out`` whose
+    tile stays within ``TILE_ROWS`` matmul rows (at least one row)."""
+    best = 1
+    for bh in range(1, h_out + 1):
+        if h_out % bh == 0 and bh * w_out <= TILE_ROWS:
+            best = bh
+    return best
+
+
+def _taps(x, w_ref, bh, w_out, *, kh, kw, stride, mxu_dtype, a_bits=None):
+    """Strided VALID conv of one (rows, W, Cin) band -> (bh*w_out, bco).
 
     The (kh, kw) loops mirror the adder-array row/column iteration; each
     tap is an MXU matmul over Cin (the FPGA's sequential input-channel
-    loop, parallelized on the MXU's contraction dim).  ``periods > 1``
-    (phase coding, bitserial only) replays the plane passes with the tiled
-    per-phase weight schedule and divides back down — exact, the sum being
-    ``periods ×`` the single-period value.  ``occ`` gates each bitserial
-    plane's tap sweep behind a ``lax.cond`` (empty plane -> no MXU work)
-    and masks the fused pass's packed bits."""
+    loop, parallelized on the MXU's contraction dim)."""
     cin = x.shape[-1]
+    acc = None
+    for r in range(kh):
+        for c in range(kw):
+            # rows/cols on the stride grid only — no discarded outputs
+            window = x[r:r + (bh - 1) * stride + 1:stride,
+                       c:c + (w_out - 1) * stride + 1:stride, :]
+            part = mxu_dot(window.reshape(bh * w_out, cin), w_ref[r, c],
+                           mxu_dtype, a_bits=a_bits)
+            acc = part if acc is None else acc + part
+    return acc
 
-    def conv_planes(plane):
-        acc = jnp.zeros((h_out * w_out, bco), jnp.int32)
-        for r in range(kh):
-            for c in range(kw):
-                # rows/cols on the stride grid only — no discarded outputs
-                window = plane[r:r + (h_out - 1) * stride + 1:stride,
-                               c:c + (w_out - 1) * stride + 1:stride, :]
-                acc = acc + mxu_dot(
-                    window.reshape(h_out * w_out, cin),
-                    w_ref[r, c].astype(jnp.int32),
-                    mxu_dtype,
-                )
-        return acc
 
+def _conv_acc(x, taps, zero, *, num_steps, method, periods, occ):
+    """All plane passes of one band in one grid step.
+
+    ``periods > 1`` (phase coding, bitserial only) replays the plane
+    passes with the tiled per-phase weight schedule and divides back down
+    — exact, the sum being ``periods ×`` the single-period value.  ``occ``
+    gates each bitserial plane's tap sweep behind a ``lax.cond`` (empty
+    plane -> no MXU work) and masks the fused pass's packed bits."""
     if method == "fused":
         if occ is not None:
             x = x & occ_mask(occ, num_steps)  # masked pass: occupied bits
-        return conv_planes(x)                 # radix identity: one pass
-
-    zero = jnp.zeros((h_out * w_out, bco), jnp.int32)
+        return taps(x, a_bits=num_steps)      # radix identity: one pass
 
     def plane_conv(shift):
-        plane = (x >> shift) & 1
         # dynamic early-exit: the whole tap sweep runs only when occupied
-        return gated(occ, shift, lambda: conv_planes(plane), zero)
+        return gated(occ, shift, lambda: taps((x >> shift) & 1), zero)
 
     acc = zero
     if periods == 1:
@@ -112,217 +122,76 @@ def _conv_acc(x, w_ref, h_out, w_out, bco, *, num_steps, method, kh, kw,
     return acc // periods
 
 
-def radix_conv2d_kernel(
-    x_ref, w_ref, o_ref, *, num_steps: int, method: str, kh: int, kw: int,
-    stride: int, periods: int = 1, mxu_dtype: str = "int32",
-):
-    """x_ref: (1, H, W, Cin) packed levels; w_ref: (kh, kw, Cin, bco);
-    o_ref: (1, H_out, W_out, bco) int32."""
-    h_out, w_out = o_ref.shape[1], o_ref.shape[2]
-    bco = o_ref.shape[3]
-    x = x_ref[0].astype(jnp.int32)            # (H, W, Cin)
-    acc = _conv_acc(x, w_ref, h_out, w_out, bco, num_steps=num_steps,
-                    method=method, kh=kh, kw=kw, stride=stride,
-                    periods=periods, mxu_dtype=mxu_dtype)
-    o_ref[0] = acc.reshape(h_out, w_out, bco)
+def _conv_kernel(*refs, num_steps, method, kh, kw, stride, periods,
+                 out_level, out_grid, mxu_dtype, sparse, epilogue,
+                 plane_parallel):
+    """One (image, C_out block, row tile[, plane]) grid step.
 
+    Refs, in order: x band, weights, [occupancy], [bias, mult], out,
+    [accumulator scratch].  Plane-parallel steps (bitserial only) run ONE
+    plane pass each — the plane index is the innermost grid dimension and
+    the weight block's index map ignores it, so the weight tile stays
+    VMEM-resident across all ``T x periods`` passes (weight-stationary);
+    the Horner chain is reassociated into ``(plane_t conv w) << shift_t``
+    terms, exact in int32.  The int32 sum then lives in the output block
+    (raw path) or the scratch tile (epilogue path) across plane steps."""
+    refs = list(refs)
+    x_ref, w_ref = refs.pop(0), refs.pop(0)
+    occ = refs.pop(0)[0] if sparse else None
+    bias_ref, mult_ref = (refs.pop(0), refs.pop(0)) if epilogue else (
+        None, None)
+    o_ref = refs.pop(0)
+    acc_ref = refs.pop(0) if refs else None
+    _, bh, w_out, bco = o_ref.shape
+    x = x_ref[0].astype(jnp.int32)            # (rows, W, Cin) band
+    zero = jnp.zeros((bh * w_out, bco), jnp.int32)
+    taps = functools.partial(_taps, w_ref=w_ref, bh=bh, w_out=w_out, kh=kh,
+                             kw=kw, stride=stride, mxu_dtype=mxu_dtype)
 
-def radix_conv2d_sparse_kernel(
-    x_ref, w_ref, occ_ref, o_ref, *, num_steps: int, method: str, kh: int,
-    kw: int, stride: int, periods: int = 1, mxu_dtype: str = "int32",
-):
-    """Occupancy-gated variant of :func:`radix_conv2d_kernel`."""
-    h_out, w_out = o_ref.shape[1], o_ref.shape[2]
-    bco = o_ref.shape[3]
-    x = x_ref[0].astype(jnp.int32)
-    acc = _conv_acc(x, w_ref, h_out, w_out, bco, num_steps=num_steps,
-                    method=method, kh=kh, kw=kw, stride=stride,
-                    periods=periods, occ=occ_ref[0], mxu_dtype=mxu_dtype)
-    o_ref[0] = acc.reshape(h_out, w_out, bco)
+    def store(acc):
+        if not epilogue:
+            o_ref[0] = acc.reshape(bh, w_out, bco)
+            return
+        # the paper's output logic on the int32 tile: identical float ops
+        # to layers.q_requantize -> bit-exact twin
+        q = jnp.floor((acc + bias_ref[...]).astype(jnp.float32)
+                      * mult_ref[...])
+        # reshape while 32-bit: Mosaic cannot regroup the rows of a uint8
+        # tile narrower than 128 lanes unless w_out is 4-row aligned
+        o_ref[0] = _project_levels(q.reshape(bh, w_out, bco),
+                                   out_level=out_level, out_grid=out_grid)
 
+    if not plane_parallel:
+        store(_conv_acc(x, taps, zero, num_steps=num_steps, method=method,
+                        periods=periods, occ=occ))
+        return
 
-def _epilogue_tile(acc, bias_ref, mult_ref, *, out_level, out_grid,
-                   h_out, w_out, bco):
-    """The fused output logic on a conv register tile — ONE copy shared
-    by the dense and occupancy-gated epilogue kernels (identical float
-    ops to layers.q_requantize -> bit-exact twin)."""
-    acc = acc + bias_ref[...]                      # (hw, bco) + (1, bco)
-    q = jnp.floor(acc.astype(jnp.float32) * mult_ref[...])
-    return _project_levels(q, out_level=out_level,
-                           out_grid=out_grid).reshape(h_out, w_out, bco)
-
-
-def radix_conv2d_epilogue_kernel(
-    x_ref, w_ref, bias_ref, mult_ref, o_ref, *, num_steps: int, method: str,
-    kh: int, kw: int, stride: int, out_level: int, periods: int = 1,
-    out_grid: str = "dense", mxu_dtype: str = "int32",
-):
-    """Fused-epilogue variant: output logic runs on the int32 register tile
-    and o_ref receives packed uint8 levels (1, H_out, W_out, bco)."""
-    h_out, w_out = o_ref.shape[1], o_ref.shape[2]
-    bco = o_ref.shape[3]
-    x = x_ref[0].astype(jnp.int32)
-    acc = _conv_acc(x, w_ref, h_out, w_out, bco, num_steps=num_steps,
-                    method=method, kh=kh, kw=kw, stride=stride,
-                    periods=periods, mxu_dtype=mxu_dtype)
-    o_ref[0] = _epilogue_tile(acc, bias_ref, mult_ref, out_level=out_level,
-                              out_grid=out_grid, h_out=h_out, w_out=w_out,
-                              bco=bco)
-
-
-def radix_conv2d_sparse_epilogue_kernel(
-    x_ref, w_ref, occ_ref, bias_ref, mult_ref, o_ref, *, num_steps: int,
-    method: str, kh: int, kw: int, stride: int, out_level: int,
-    periods: int = 1, out_grid: str = "dense", mxu_dtype: str = "int32",
-):
-    """Occupancy-gated fused-epilogue variant."""
-    h_out, w_out = o_ref.shape[1], o_ref.shape[2]
-    bco = o_ref.shape[3]
-    x = x_ref[0].astype(jnp.int32)
-    acc = _conv_acc(x, w_ref, h_out, w_out, bco, num_steps=num_steps,
-                    method=method, kh=kh, kw=kw, stride=stride,
-                    periods=periods, occ=occ_ref[0], mxu_dtype=mxu_dtype)
-    o_ref[0] = _epilogue_tile(acc, bias_ref, mult_ref, out_level=out_level,
-                              out_grid=out_grid, h_out=h_out, w_out=w_out,
-                              bco=bco)
-
-
-def _conv_plane_contrib(x_ref, w_ref, occ_ref, *, num_steps, kh, kw, stride,
-                        h_out, w_out, bco, mxu_dtype):
-    """One plane-parallel grid step's (h_out*w_out, bco) contribution.
-
-    The plane index is grid dimension 2 (innermost), so the weight block
-    — whose index map ignores it — stays VMEM-resident across all
-    ``T x periods`` plane passes (weight-stationary).  The Horner chain
-    is reassociated into ``(plane_t conv w) << shift_t`` terms, exact in
-    int32."""
-    x = x_ref[0].astype(jnp.int32)
-    cin = x.shape[-1]
-    t_idx = pl.program_id(2)
+    t_idx = pl.program_id(3)
+    last = t_idx == num_steps * periods - 1
     shift = num_steps - 1 - jax.lax.rem(t_idx, num_steps)
-    plane = (x >> shift) & 1
-    zero = jnp.zeros((h_out * w_out, bco), jnp.int32)
-    occ = occ_ref[0] if occ_ref is not None else None
+    contrib = gated(occ, shift, lambda: taps((x >> shift) & 1) << shift,
+                    zero)
+    if epilogue:
+        @pl.when(t_idx == 0)
+        def _init():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    def taps():
-        acc = zero
-        for r in range(kh):
-            for c in range(kw):
-                window = plane[r:r + (h_out - 1) * stride + 1:stride,
-                               c:c + (w_out - 1) * stride + 1:stride, :]
-                acc = acc + mxu_dot(
-                    window.reshape(h_out * w_out, cin),
-                    w_ref[r, c].astype(jnp.int32),
-                    mxu_dtype,
-                )
-        return acc << shift
+        acc_ref[...] += contrib
 
-    return gated(occ, shift, taps, zero)
-
-
-def radix_conv2d_plane_kernel(
-    x_ref, w_ref, o_ref, *, num_steps: int, kh: int, kw: int, stride: int,
-    periods: int = 1, mxu_dtype: str = "int32",
-):
-    """Plane-parallel tile: o_ref is the int32 accumulator across the
-    plane grid dimension; the phase divide lands on the final plane."""
-    h_out, w_out, bco = o_ref.shape[1], o_ref.shape[2], o_ref.shape[3]
-    t_idx = pl.program_id(2)
+        @pl.when(last)
+        def _epilogue():
+            store(acc_ref[...] // periods if periods > 1 else acc_ref[...])
+        return
 
     @pl.when(t_idx == 0)
-    def _init():
+    def _init_out():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    contrib = _conv_plane_contrib(
-        x_ref, w_ref, None, num_steps=num_steps, kh=kh, kw=kw, stride=stride,
-        h_out=h_out, w_out=w_out, bco=bco, mxu_dtype=mxu_dtype)
-    o_ref[0] = o_ref[0] + contrib.reshape(h_out, w_out, bco)
+    o_ref[0] = o_ref[0] + contrib.reshape(bh, w_out, bco)
     if periods > 1:
-        @pl.when(t_idx == num_steps * periods - 1)
+        @pl.when(last)
         def _div():
             o_ref[...] = o_ref[...] // periods
-
-
-def radix_conv2d_plane_sparse_kernel(
-    x_ref, w_ref, occ_ref, o_ref, *, num_steps: int, kh: int, kw: int,
-    stride: int, periods: int = 1, mxu_dtype: str = "int32",
-):
-    """Occupancy-gated plane-parallel tile (empty plane -> the grid
-    step's whole tap sweep is skipped)."""
-    h_out, w_out, bco = o_ref.shape[1], o_ref.shape[2], o_ref.shape[3]
-    t_idx = pl.program_id(2)
-
-    @pl.when(t_idx == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    contrib = _conv_plane_contrib(
-        x_ref, w_ref, occ_ref, num_steps=num_steps, kh=kh, kw=kw,
-        stride=stride, h_out=h_out, w_out=w_out, bco=bco,
-        mxu_dtype=mxu_dtype)
-    o_ref[0] = o_ref[0] + contrib.reshape(h_out, w_out, bco)
-    if periods > 1:
-        @pl.when(t_idx == num_steps * periods - 1)
-        def _div():
-            o_ref[...] = o_ref[...] // periods
-
-
-def radix_conv2d_plane_epilogue_kernel(
-    x_ref, w_ref, bias_ref, mult_ref, o_ref, acc_ref, *, num_steps: int,
-    kh: int, kw: int, stride: int, out_level: int, periods: int = 1,
-    out_grid: str = "dense", mxu_dtype: str = "int32",
-):
-    """Plane-parallel fused-epilogue tile: unlike the sequential variant
-    (whose register tile lives within one grid step) the accumulator must
-    survive across plane grid steps, so it lives in the ``acc_ref`` VMEM
-    scratch; the output logic runs on the final plane visit."""
-    h_out, w_out, bco = o_ref.shape[1], o_ref.shape[2], o_ref.shape[3]
-    t_idx = pl.program_id(2)
-
-    @pl.when(t_idx == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    acc_ref[...] += _conv_plane_contrib(
-        x_ref, w_ref, None, num_steps=num_steps, kh=kh, kw=kw, stride=stride,
-        h_out=h_out, w_out=w_out, bco=bco, mxu_dtype=mxu_dtype)
-
-    @pl.when(t_idx == num_steps * periods - 1)
-    def _epilogue():
-        acc = acc_ref[...]
-        if periods > 1:
-            acc = acc // periods
-        o_ref[0] = _epilogue_tile(acc, bias_ref, mult_ref,
-                                  out_level=out_level, out_grid=out_grid,
-                                  h_out=h_out, w_out=w_out, bco=bco)
-
-
-def radix_conv2d_plane_sparse_epilogue_kernel(
-    x_ref, w_ref, occ_ref, bias_ref, mult_ref, o_ref, acc_ref, *,
-    num_steps: int, kh: int, kw: int, stride: int, out_level: int,
-    periods: int = 1, out_grid: str = "dense", mxu_dtype: str = "int32",
-):
-    """Occupancy-gated plane-parallel fused-epilogue tile."""
-    h_out, w_out, bco = o_ref.shape[1], o_ref.shape[2], o_ref.shape[3]
-    t_idx = pl.program_id(2)
-
-    @pl.when(t_idx == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    acc_ref[...] += _conv_plane_contrib(
-        x_ref, w_ref, occ_ref, num_steps=num_steps, kh=kh, kw=kw,
-        stride=stride, h_out=h_out, w_out=w_out, bco=bco,
-        mxu_dtype=mxu_dtype)
-
-    @pl.when(t_idx == num_steps * periods - 1)
-    def _epilogue():
-        acc = acc_ref[...]
-        if periods > 1:
-            acc = acc // periods
-        o_ref[0] = _epilogue_tile(acc, bias_ref, mult_ref,
-                                  out_level=out_level, out_grid=out_grid,
-                                  h_out=h_out, w_out=w_out, bco=bco)
 
 
 @functools.partial(
@@ -346,7 +215,7 @@ def radix_conv2d_pallas(
     out_level: Optional[int] = None,
     out_grid: str = "dense",
     occupancy: Optional[jax.Array] = None,
-    mxu_dtype: str = "int32",
+    mxu_dtype: str = "int8",
     plane_parallel: bool = False,
 ) -> jax.Array:
     """(N, H, W, Cin) uint8 @ (KH, KW, Cin, Cout) int8 -> VALID conv.
@@ -364,9 +233,9 @@ def radix_conv2d_pallas(
     sparsity-aware schedule (empty planes skipped/masked, bit-exact).
     ``mxu_dtype`` selects the per-plane dot lowering (see
     ``radix_matmul.mxu_dot``); ``plane_parallel`` (bitserial only) moves
-    the plane loop into grid dimension 2 under weight-stationary specs.
-    Cout must be a multiple of ``bco`` (ops.py pads); ``stride``
-    subsamples inside the kernel."""
+    the plane loop into the innermost grid dimension under
+    weight-stationary specs.  Cout must be a multiple of ``bco`` (ops.py
+    pads); ``stride`` subsamples inside the kernel."""
     n, h, w, cin = x_q.shape
     kh, kw, cin2, cout = w_q.shape
     assert cin == cin2, (x_q.shape, w_q.shape)
@@ -376,110 +245,60 @@ def radix_conv2d_pallas(
                          "(the fused dataflow has a single pass)")
     h_out = (h - kh) // stride + 1
     w_out = (w - kw) // stride + 1
+    bh = row_tile(h_out, w_out)
+    rows_in = (bh - 1) * stride + kh
 
+    grid = (n, cout // bco, h_out // bh)
     if plane_parallel:
-        grid = (n, cout // bco, num_steps * periods)
-        in_specs = [
-            pl.BlockSpec((1, h, w, cin), lambda b, co, t: (b, 0, 0, 0)),
-            pl.BlockSpec((kh, kw, cin, bco), lambda b, co, t: (0, 0, 0, co)),
-        ]
-        o_spec = pl.BlockSpec((1, h_out, w_out, bco),
-                              lambda b, co, t: (b, 0, 0, co))
-        occ_spec = pl.BlockSpec((1, OCC_LANES), lambda b, co, t: (0, 0))
-        row_spec = pl.BlockSpec((1, bco), lambda b, co, t: (0, co))
-    else:
-        grid = (n, cout // bco)
-        in_specs = [
-            pl.BlockSpec((1, h, w, cin), lambda b, co: (b, 0, 0, 0)),
-            pl.BlockSpec((kh, kw, cin, bco), lambda b, co: (0, 0, 0, co)),
-        ]
-        o_spec = pl.BlockSpec((1, h_out, w_out, bco),
-                              lambda b, co: (b, 0, 0, co))
-        occ_spec = pl.BlockSpec((1, OCC_LANES), lambda b, co: (0, 0))
-        row_spec = pl.BlockSpec((1, bco), lambda b, co: (0, co))
+        grid += (num_steps * periods,)
+
+    def spec(block, index):
+        # the plane index (grid dim 3, when present) addresses no block
+        return pl.BlockSpec(block, lambda b, co, r, *t: index(b, co, r))
+
+    specs = [
+        # element-indexed band: the index map returns element offsets,
+        # so consecutive bands overlap by the kh - stride halo rows
+        spec(tuple(pl.Element(d) for d in (1, rows_in, w, cin)),
+             lambda b, co, r: (b, r * bh * stride, 0, 0)),
+        spec((kh, kw, cin, bco), lambda b, co, r: (0, 0, 0, co)),
+    ]
+    args = [x_q, w_q]
     sparse = occupancy is not None
     if sparse:
         assert occupancy.shape == (1, OCC_LANES), occupancy.shape
-        occupancy = occupancy.astype(jnp.int32)
-
-    if mult is None:
-        if plane_parallel:
-            kernel = functools.partial(
-                radix_conv2d_plane_sparse_kernel if sparse
-                else radix_conv2d_plane_kernel,
-                num_steps=num_steps, kh=kh, kw=kw, stride=stride,
-                periods=periods, mxu_dtype=mxu_dtype)
-        elif sparse:
-            kernel = functools.partial(
-                radix_conv2d_sparse_kernel, num_steps=num_steps,
-                method=method, kh=kh, kw=kw, stride=stride, periods=periods,
-                mxu_dtype=mxu_dtype)
-        else:
-            kernel = functools.partial(
-                radix_conv2d_kernel, num_steps=num_steps, method=method,
-                kh=kh, kw=kw, stride=stride, periods=periods,
-                mxu_dtype=mxu_dtype)
-        if sparse:
-            specs, args = in_specs + [occ_spec], (x_q, w_q, occupancy)
-        else:
-            specs, args = in_specs, (x_q, w_q)
-        return pl.pallas_call(
-            kernel,
-            grid=grid,
-            in_specs=specs,
-            out_specs=o_spec,
-            out_shape=jax.ShapeDtypeStruct((n, h_out, w_out, cout), jnp.int32),
-            interpret=interpret,
-        )(*args)
-
-    out_steps = num_steps if out_steps is None else out_steps
-    out_level = (1 << out_steps) - 1 if out_level is None else out_level
-    assert out_level <= 255, "packed uint8 epilogue requires out_level <= 255"
-    if bias is None:
-        bias = jnp.zeros((1, cout), jnp.int32)
-    assert bias.shape == (1, cout) and mult.shape == (1, cout), (
-        bias.shape, mult.shape)
+        specs.append(spec((1, OCC_LANES), lambda b, co, r: (0, 0)))
+        args.append(occupancy.astype(jnp.int32))
+    epilogue = mult is not None
     scratch = []
-    if plane_parallel:
-        # the sequential epilogue accumulates in registers within one grid
-        # step; across plane grid steps the accumulator needs VMEM scratch
-        scratch = [pltpu.VMEM((h_out * w_out, bco), jnp.int32)]
-        if sparse:
-            kernel = functools.partial(
-                radix_conv2d_plane_sparse_epilogue_kernel,
-                num_steps=num_steps, kh=kh, kw=kw, stride=stride,
-                out_level=out_level, periods=periods, out_grid=out_grid,
-                mxu_dtype=mxu_dtype)
-            specs = in_specs + [occ_spec, row_spec, row_spec]
-            args = (x_q, w_q, occupancy, bias, mult.astype(jnp.float32))
-        else:
-            kernel = functools.partial(
-                radix_conv2d_plane_epilogue_kernel,
-                num_steps=num_steps, kh=kh, kw=kw, stride=stride,
-                out_level=out_level, periods=periods, out_grid=out_grid,
-                mxu_dtype=mxu_dtype)
-            specs = in_specs + [row_spec, row_spec]
-            args = (x_q, w_q, bias, mult.astype(jnp.float32))
-    elif sparse:
-        kernel = functools.partial(
-            radix_conv2d_sparse_epilogue_kernel, num_steps=num_steps,
-            method=method, kh=kh, kw=kw, stride=stride, out_level=out_level,
-            periods=periods, out_grid=out_grid, mxu_dtype=mxu_dtype)
-        specs = in_specs + [occ_spec, row_spec, row_spec]
-        args = (x_q, w_q, occupancy, bias, mult.astype(jnp.float32))
-    else:
-        kernel = functools.partial(
-            radix_conv2d_epilogue_kernel, num_steps=num_steps, method=method,
-            kh=kh, kw=kw, stride=stride, out_level=out_level,
-            periods=periods, out_grid=out_grid, mxu_dtype=mxu_dtype)
-        specs = in_specs + [row_spec, row_spec]
-        args = (x_q, w_q, bias, mult.astype(jnp.float32))
+    if epilogue:
+        out_steps = num_steps if out_steps is None else out_steps
+        out_level = (1 << out_steps) - 1 if out_level is None else out_level
+        assert out_level <= 255, (
+            "packed uint8 epilogue requires out_level <= 255")
+        if bias is None:
+            bias = jnp.zeros((1, cout), jnp.int32)
+        assert bias.shape == (1, cout) and mult.shape == (1, cout), (
+            bias.shape, mult.shape)
+        row = spec((1, bco), lambda b, co, r: (0, co))
+        specs += [row, row]
+        args += [bias, mult.astype(jnp.float32)]
+        if plane_parallel:
+            # the accumulator must survive across plane grid steps
+            scratch = [pltpu.VMEM((bh * w_out, bco), jnp.int32)]
+
+    kernel = functools.partial(
+        _conv_kernel, num_steps=num_steps, method=method, kh=kh, kw=kw,
+        stride=stride, periods=periods, out_level=out_level,
+        out_grid=out_grid, mxu_dtype=mxu_dtype, sparse=sparse,
+        epilogue=epilogue, plane_parallel=plane_parallel)
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=specs,
-        out_specs=o_spec,
-        out_shape=jax.ShapeDtypeStruct((n, h_out, w_out, cout), jnp.uint8),
+        out_specs=spec((1, bh, w_out, bco), lambda b, co, r: (b, r, 0, co)),
+        out_shape=jax.ShapeDtypeStruct((n, h_out, w_out, cout),
+                                       jnp.uint8 if epilogue else jnp.int32),
         scratch_shapes=scratch,
         interpret=interpret,
     )(*args)

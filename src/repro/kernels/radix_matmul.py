@@ -61,15 +61,17 @@ from jax.experimental.pallas import tpu as pltpu
 
 __all__ = [
     "OCC_LANES",
+    "int8_contract",
     "mxu_dot",
-    "radix_matmul_kernel",
-    "radix_matmul_epilogue_kernel",
     "radix_matmul_pallas",
 ]
 
 OCC_LANES = 128
 """Lane-aligned width of the plane-occupancy row the kernels consume
 (entries beyond the actual bit count are ignored)."""
+
+INT8_SLICE_BITS = 7
+"""Bits of a non-negative operand that one int8 MXU pass carries."""
 
 
 def occ_mask(occ, num_steps: int) -> jax.Array:
@@ -82,61 +84,93 @@ def occ_mask(occ, num_steps: int) -> jax.Array:
     return mask
 
 
-def gated(occ, shift: int, fn, zero) -> jax.Array:
+def gated(occ, shift, fn, zero) -> jax.Array:
     """One occupancy-gated plane pass: run ``fn()`` only when plane
     ``shift`` is occupied, else return the ``zero`` tile (``occ=None``
     means ungated).  The ``lax.cond`` is the bitserial dynamic
-    early-exit; validated in interpret mode (CPU CI) — on a real TPU the
-    predicate is a VMEM-loaded scalar, which Mosaic must lower to an
-    scf.if for the skip to pay off (hardware validation pending; a
-    scalar-prefetch SMEM row is the fallback if it does not)."""
+    early-exit.  ``shift`` may be traced (plane-parallel grids take it
+    from ``pl.program_id``); the TPU lowering cannot slice a vector at a
+    traced index, so that bit is read with a masked lane reduction."""
     if occ is None:
         return fn()
-    return jax.lax.cond(occ[shift] > 0, fn, lambda: zero)
+    if isinstance(shift, int):
+        bit = occ[shift]
+    else:
+        lanes = jax.lax.broadcasted_iota(jnp.int32, occ.shape, 0)
+        bit = jnp.max(jnp.where(lanes == shift, occ, 0))
+    return jax.lax.cond(bit > 0, fn, lambda: zero)
 
 
-def mxu_dot(a, w, mxu_dtype: str = "int32",
-            acc_dtype: str = "int32") -> jax.Array:
+def _int8_slices(x, bits: Optional[int]):
+    """``(shift, int8 slice)`` pieces of a non-negative integer operand.
+
+    ``bits=None`` (or ``<= 7``) means the operand already fits int8 —
+    int8 weights, plane bits, packed levels with ``T <= 7`` — and it goes
+    in whole.  A wider operand (packed levels with ``T >= 8``, a sum-pool
+    carry) is cut into 7-bit slices, each of which fits int8."""
+    if bits is None or bits <= INT8_SLICE_BITS:
+        return [(0, x.astype(jnp.int8))]
+    xi = x.astype(jnp.int32)
+    mask = (1 << INT8_SLICE_BITS) - 1
+    return [(s, ((xi >> s) & mask).astype(jnp.int8))
+            for s in range(0, bits, INT8_SLICE_BITS)]
+
+
+def int8_contract(contract, a, b, *, a_bits: Optional[int] = None,
+                  b_bits: Optional[int] = None) -> jax.Array:
+    """Exact integer contraction as int8 x int8 -> int32 MXU passes.
+
+    ``contract(a8, b8)`` is the int8 contraction (``dot_general`` or
+    ``conv_general_dilated`` with ``preferred_element_type=int32``);
+    ``a_bits``/``b_bits`` bound non-negative operands (see
+    :func:`_int8_slices`).  Operands that fit int8 take one pass; a
+    wider one takes one pass per 7-bit slice, shifted back into place in
+    int32 — the same sum, so exact wherever an int32 accumulation is."""
+    acc = None
+    for sa, a8 in _int8_slices(a, a_bits):
+        for sb, b8 in _int8_slices(b, b_bits):
+            part = contract(a8, b8)
+            if sa + sb:
+                part = part << (sa + sb)
+            acc = part if acc is None else acc + part
+    return acc
+
+
+def mxu_dot(a, w, mxu_dtype: str = "int8", acc_dtype: str = "int32", *,
+            a_bits: Optional[int] = None) -> jax.Array:
     """One plane/packed contraction under the selected MXU lowering.
 
-    ``"int32"`` is the always-exact reference lowering.  ``"int8"`` casts
-    both operands to int8 with ``preferred_element_type=int32`` — the
-    TPU-native path: the MXU runs int8xint8->int32 at full systolic rate,
-    and the autotuner only selects it when ``autotune.exact_lowering``
-    proves the operands fit (plane bits always do; packed levels iff
-    ``T <= 7``).  ``"f32"`` runs the dot at the BLAS float rate — exact
-    while every partial sum stays under the 24-bit f32 mantissa (again
-    guarded by ``exact_lowering``); this is the winner on CPU CI, where
-    XLA has no vectorized integer GEMM.  Every branch casts its own
-    operands to the lowering dtype, so callers may hand either raw
-    packed/int8 tensors or operands already held in the lowering dtype
-    (the cast is a no-op then — how the engine and the bench avoid a
-    per-call weight convert: a weight captured in the jitted plan is
-    converted once at compile time).  The result is int32, except that
-    ``acc_dtype="f32"`` (legal only with ``mxu_dtype="f32"``, i.e. the
-    ``act_dtype="f32"`` boundary layout) keeps the exact-integer f32
-    accumulator — the final int32 convert is an unfused extra pass over
-    the output on CPU, and a strategy whose layer boundary is f32 has no
-    use for it."""
-    if mxu_dtype == "int32":
-        return jax.lax.dot_general(
-            a.astype(jnp.int32), w.astype(jnp.int32),
-            (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32)
+    ``"int8"`` (the default) feeds the MXU int8 operands with
+    ``preferred_element_type=int32`` — the lowering the TPU compiles, at
+    the full int8 systolic rate.  It is always exact: ``a_bits`` bounds
+    the activation operand, and one wider than int8 is sliced
+    (:func:`int8_contract`).  ``"f32"`` runs the dot at the BLAS float
+    rate — exact while every partial sum stays under the 24-bit f32
+    mantissa (guarded by ``autotune.exact_lowering``); the XLA twin's
+    winner on CPU, where XLA has no vectorized integer GEMM.  Every
+    branch casts its own operands, so callers may hand raw packed/int8
+    tensors or operands already held in the lowering dtype (a weight
+    captured in a jitted plan converts once, at compile time).  The
+    result is int32, except that ``acc_dtype="f32"`` (legal only with
+    ``mxu_dtype="f32"``, i.e. the ``act_dtype="f32"`` boundary layout)
+    keeps the exact-integer f32 accumulator."""
+    dn = (((1,), (0,)), ((), ()))
     if mxu_dtype == "int8":
-        return jax.lax.dot_general(
-            a.astype(jnp.int8), w.astype(jnp.int8),
-            (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32)
+        return int8_contract(
+            lambda x, y: jax.lax.dot_general(
+                x, y, dn, preferred_element_type=jnp.int32),
+            a, w, a_bits=a_bits)
     if mxu_dtype == "f32":
         out = jax.lax.dot_general(
-            a.astype(jnp.float32), w.astype(jnp.float32),
-            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            a.astype(jnp.float32), w.astype(jnp.float32), dn,
+            preferred_element_type=jnp.float32)
         return out if acc_dtype == "f32" else out.astype(jnp.int32)
     raise ValueError(f"unknown mxu_dtype {mxu_dtype!r}")
 
 
 def _accumulate_tile(x, w, *, num_steps: int, method: str,
                      periods: int = 1, occ=None,
-                     mxu_dtype: str = "int32") -> jax.Array:
+                     mxu_dtype: str = "int8") -> jax.Array:
     """(bm, bk) x (bk, bn) int32 partial product, bit-serial or single-pass.
 
     ``periods > 1`` (phase coding) replays the ``num_steps`` plane passes
@@ -151,14 +185,14 @@ def _accumulate_tile(x, w, *, num_steps: int, method: str,
     way: a globally empty plane contributes zero.
     """
 
-    def dot(a):
-        return mxu_dot(a, w, mxu_dtype)
+    def dot(a, bits=None):
+        return mxu_dot(a, w, mxu_dtype, a_bits=bits)
 
     if method == "fused":
         # radix identity: one int MXU pass over packed levels
         if occ is not None:
             x = x & occ_mask(occ, num_steps)   # masked pass: occupied bits
-        return dot(x)
+        return dot(x, num_steps)
 
     zero = jnp.zeros((x.shape[0], w.shape[1]), jnp.int32)
 
@@ -197,25 +231,20 @@ def _project_levels(q, *, out_level: int, out_grid: str) -> jax.Array:
     return lvl.astype(jnp.uint8)
 
 
-def _accumulate_step(x_ref, w_ref, occ_ref, acc_ref, *, num_steps, method,
-                     periods, mxu_dtype="int32"):
-    """Shared K-grid accumulation body (occ_ref is None when dense)."""
-    k_idx = pl.program_id(2)
-
-    @pl.when(k_idx == 0)
+def _accumulate_step(x_ref, w_ref, occ, acc_ref, *, num_steps, method,
+                     periods, mxu_dtype):
+    """K-grid accumulation body: all plane passes in one grid step."""
+    @pl.when(pl.program_id(2) == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     x = x_ref[...].astype(jnp.int32)          # (bm, bk) packed levels
-    w = w_ref[...].astype(jnp.int32)          # (bk, bn) int weights
-    occ = occ_ref[0] if occ_ref is not None else None
-    acc_ref[...] += _accumulate_tile(x, w, num_steps=num_steps,
+    acc_ref[...] += _accumulate_tile(x, w_ref[...], num_steps=num_steps,
                                      method=method, periods=periods,
                                      occ=occ, mxu_dtype=mxu_dtype)
 
 
-def _plane_step(x_ref, w_ref, occ_ref, acc_ref, *, num_steps, periods,
-                mxu_dtype="int32"):
+def _plane_step(x_ref, w_ref, occ, acc_ref, *, num_steps, mxu_dtype):
     """Plane-parallel accumulation body: one grid step = ONE plane pass.
 
     The plane index ``t`` is grid dimension 3 (innermost), so the weight
@@ -226,151 +255,66 @@ def _plane_step(x_ref, w_ref, occ_ref, acc_ref, *, num_steps, periods,
     form ``acc += (plane_t @ w) << shift_t`` (the same sum, reassociated
     — exact in int32), because grid steps cannot carry the
     multiply-by-two dependency chain."""
-    k_idx = pl.program_id(2)
     t_idx = pl.program_id(3)
 
-    @pl.when((k_idx == 0) & (t_idx == 0))
+    @pl.when((pl.program_id(2) == 0) & (t_idx == 0))
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     x = x_ref[...].astype(jnp.int32)          # (bm, bk) packed levels
-    w = w_ref[...].astype(jnp.int32)          # (bk, bn) int weights
+    w = w_ref[...]                            # (bk, bn) int8 weights
     shift = num_steps - 1 - jax.lax.rem(t_idx, num_steps)
     plane = (x >> shift) & 1
     zero = jnp.zeros((x.shape[0], w.shape[1]), jnp.int32)
-    occ = occ_ref[0] if occ_ref is not None else None
     acc_ref[...] += gated(occ, shift,
                           lambda: mxu_dot(plane, w, mxu_dtype) << shift,
                           zero)
 
 
-def _plane_last(num_steps: int, periods: int):
-    """Predicate: this grid step is the final (K, plane) visit."""
-    return ((pl.program_id(2) == pl.num_programs(2) - 1)
-            & (pl.program_id(3) == num_steps * periods - 1))
+def _matmul_kernel(*refs, num_steps, method, periods, out_level, out_grid,
+                   mxu_dtype, sparse, epilogue, plane_parallel):
+    """One (bm, bk) x (bk, bn) tile[, one plane] of the (M, N, K[, plane])
+    grid.
 
+    Refs, in order: x, w, [occupancy], [bias, mult], out, [accumulator
+    scratch].  The int32 sum accumulates across the K (and plane) grid in
+    the output block itself (raw path) or in the VMEM scratch tile
+    (epilogue path), where on the final visit the output logic — bias +
+    requant multiply + clamp + level-grid projection, identical float
+    ops to ``layers.q_requantize`` — runs in-register and only the packed
+    uint8 level reaches HBM.  Occupancy-gated plane passes skip when
+    their occupancy bit is 0 (bitserial) / packed bits mask to the
+    occupied lanes (fused)."""
+    refs = list(refs)
+    x_ref, w_ref = refs.pop(0), refs.pop(0)
+    occ = refs.pop(0)[0] if sparse else None
+    bias_ref, mult_ref = (refs.pop(0), refs.pop(0)) if epilogue else (
+        None, None)
+    o_ref = refs.pop(0)
+    acc_ref = refs.pop(0) if epilogue else o_ref
+    last = pl.program_id(2) == pl.num_programs(2) - 1
+    if plane_parallel:
+        _plane_step(x_ref, w_ref, occ, acc_ref, num_steps=num_steps,
+                    mxu_dtype=mxu_dtype)
+        last = last & (pl.program_id(3) == num_steps * periods - 1)
+    else:
+        _accumulate_step(x_ref, w_ref, occ, acc_ref, num_steps=num_steps,
+                         method=method, periods=periods, mxu_dtype=mxu_dtype)
+    # the sequential path divides the phase replay back down per tile;
+    # plane-parallel steps can only do it on the final visit
+    divide = plane_parallel and periods > 1
+    if not (epilogue or divide):
+        return
 
-def radix_matmul_kernel(x_ref, w_ref, o_ref, *, num_steps: int, method: str,
-                        periods: int = 1, mxu_dtype: str = "int32"):
-    """One (bm, bk) x (bk, bn) tile; accumulates into o_ref across the K grid."""
-    _accumulate_step(x_ref, w_ref, None, o_ref, num_steps=num_steps,
-                     method=method, periods=periods, mxu_dtype=mxu_dtype)
-
-
-def radix_matmul_sparse_kernel(x_ref, w_ref, occ_ref, o_ref, *,
-                               num_steps: int, method: str, periods: int = 1,
-                               mxu_dtype: str = "int32"):
-    """Occupancy-gated tile: plane passes skip when their occupancy bit
-    is 0 (bitserial) / packed bits mask to the occupied lanes (fused)."""
-    _accumulate_step(x_ref, w_ref, occ_ref, o_ref, num_steps=num_steps,
-                     method=method, periods=periods, mxu_dtype=mxu_dtype)
-
-
-def radix_matmul_plane_kernel(x_ref, w_ref, o_ref, *, num_steps: int,
-                              periods: int = 1, mxu_dtype: str = "int32"):
-    """Plane-parallel tile: o_ref is the int32 accumulator across the
-    (K, plane) grid; the phase divide lands on the final visit."""
-    _plane_step(x_ref, w_ref, None, o_ref, num_steps=num_steps,
-                periods=periods, mxu_dtype=mxu_dtype)
-    if periods > 1:
-        @pl.when(_plane_last(num_steps, periods))
-        def _div():
-            o_ref[...] = o_ref[...] // periods
-
-
-def radix_matmul_plane_sparse_kernel(x_ref, w_ref, occ_ref, o_ref, *,
-                                     num_steps: int, periods: int = 1,
-                                     mxu_dtype: str = "int32"):
-    """Occupancy-gated plane-parallel tile (empty plane -> whole grid
-    step's MXU pass skipped)."""
-    _plane_step(x_ref, w_ref, occ_ref, o_ref, num_steps=num_steps,
-                periods=periods, mxu_dtype=mxu_dtype)
-    if periods > 1:
-        @pl.when(_plane_last(num_steps, periods))
-        def _div():
-            o_ref[...] = o_ref[...] // periods
-
-
-def _epilogue_store(acc_ref, bias_ref, mult_ref, o_ref, *, out_level: int,
-                    out_grid: str):
-    """The fused output logic: bias + requant multiply + grid projection.
-
-    Identical float ops to ``layers.q_requantize`` (then the grid
-    projection for non-dense schedules) -> bit-exact twin."""
-    acc = acc_ref[...] + bias_ref[...]                # (bm,bn) + (1,bn)
-    q = jnp.floor(acc.astype(jnp.float32) * mult_ref[...])
-    o_ref[...] = _project_levels(q, out_level=out_level, out_grid=out_grid)
-
-
-def radix_matmul_epilogue_kernel(
-    x_ref, w_ref, bias_ref, mult_ref, o_ref, acc_ref,
-    *, num_steps: int, method: str, out_level: int, periods: int = 1,
-    out_grid: str = "dense", mxu_dtype: str = "int32",
-):
-    """Fused-epilogue tile: int32 accumulation lives in the ``acc_ref`` VMEM
-    scratch; on the final K step the output logic (bias + requant multiply +
-    clamp + level-grid projection) runs in-register and only the packed
-    uint8 level reaches o_ref."""
-    _accumulate_step(x_ref, w_ref, None, acc_ref, num_steps=num_steps,
-                     method=method, periods=periods, mxu_dtype=mxu_dtype)
-
-    @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
-    def _epilogue():
-        _epilogue_store(acc_ref, bias_ref, mult_ref, o_ref,
-                        out_level=out_level, out_grid=out_grid)
-
-
-def radix_matmul_sparse_epilogue_kernel(
-    x_ref, w_ref, occ_ref, bias_ref, mult_ref, o_ref, acc_ref,
-    *, num_steps: int, method: str, out_level: int, periods: int = 1,
-    out_grid: str = "dense", mxu_dtype: str = "int32",
-):
-    """Occupancy-gated fused-epilogue tile (sparse accumulate + output
-    logic)."""
-    _accumulate_step(x_ref, w_ref, occ_ref, acc_ref, num_steps=num_steps,
-                     method=method, periods=periods, mxu_dtype=mxu_dtype)
-
-    @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
-    def _epilogue():
-        _epilogue_store(acc_ref, bias_ref, mult_ref, o_ref,
-                        out_level=out_level, out_grid=out_grid)
-
-
-def radix_matmul_plane_epilogue_kernel(
-    x_ref, w_ref, bias_ref, mult_ref, o_ref, acc_ref,
-    *, num_steps: int, out_level: int, periods: int = 1,
-    out_grid: str = "dense", mxu_dtype: str = "int32",
-):
-    """Plane-parallel fused-epilogue tile: the accumulator scratch
-    persists across the (K, plane) grid; on the final visit the phase
-    divide (if any) and the output logic run before the packed uint8
-    store."""
-    _plane_step(x_ref, w_ref, None, acc_ref, num_steps=num_steps,
-                periods=periods, mxu_dtype=mxu_dtype)
-
-    @pl.when(_plane_last(num_steps, periods))
-    def _epilogue():
-        if periods > 1:
+    @pl.when(last)
+    def _finish():
+        if divide:
             acc_ref[...] = acc_ref[...] // periods
-        _epilogue_store(acc_ref, bias_ref, mult_ref, o_ref,
-                        out_level=out_level, out_grid=out_grid)
-
-
-def radix_matmul_plane_sparse_epilogue_kernel(
-    x_ref, w_ref, occ_ref, bias_ref, mult_ref, o_ref, acc_ref,
-    *, num_steps: int, out_level: int, periods: int = 1,
-    out_grid: str = "dense", mxu_dtype: str = "int32",
-):
-    """Occupancy-gated plane-parallel fused-epilogue tile."""
-    _plane_step(x_ref, w_ref, occ_ref, acc_ref, num_steps=num_steps,
-                periods=periods, mxu_dtype=mxu_dtype)
-
-    @pl.when(_plane_last(num_steps, periods))
-    def _epilogue():
-        if periods > 1:
-            acc_ref[...] = acc_ref[...] // periods
-        _epilogue_store(acc_ref, bias_ref, mult_ref, o_ref,
-                        out_level=out_level, out_grid=out_grid)
+        if epilogue:
+            acc = acc_ref[...] + bias_ref[...]        # (bm,bn) + (1,bn)
+            q = jnp.floor(acc.astype(jnp.float32) * mult_ref[...])
+            o_ref[...] = _project_levels(q, out_level=out_level,
+                                         out_grid=out_grid)
 
 
 @functools.partial(
@@ -396,7 +340,7 @@ def radix_matmul_pallas(
     out_level: Optional[int] = None,
     out_grid: str = "dense",
     occupancy: Optional[jax.Array] = None,
-    mxu_dtype: str = "int32",
+    mxu_dtype: str = "int8",
     plane_parallel: bool = False,
 ) -> jax.Array:
     """(M, K) uint8 levels @ (K, N) int8 -> (M, N).
@@ -439,99 +383,50 @@ def radix_matmul_pallas(
         raise ValueError("plane_parallel requires method='bitserial' "
                          "(the fused dataflow has a single pass)")
 
+    grid = (m // bm, n // bn, k // bk)
     if plane_parallel:
         # grid dim 3 = plane index, innermost: the weight block (index
         # map ignores t) stays resident across the whole spike train.
-        grid = (m // bm, n // bn, k // bk, num_steps * periods)
-        x_spec = pl.BlockSpec((bm, bk), lambda i, j, kk, t: (i, kk))
-        w_spec = pl.BlockSpec((bk, bn), lambda i, j, kk, t: (kk, j))
-        o_spec = pl.BlockSpec((bm, bn), lambda i, j, kk, t: (i, j))
-        occ_spec = pl.BlockSpec((1, OCC_LANES), lambda i, j, kk, t: (0, 0))
-    else:
-        grid = (m // bm, n // bn, k // bk)
-        x_spec = pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk))
-        w_spec = pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j))
-        o_spec = pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j))
-        occ_spec = pl.BlockSpec((1, OCC_LANES), lambda i, j, kk: (0, 0))
+        grid += (num_steps * periods,)
+
+    def spec(block, index):
+        return pl.BlockSpec(block, lambda i, j, kk, *t: index(i, j, kk))
+
+    specs = [spec((bm, bk), lambda i, j, kk: (i, kk)),
+             spec((bk, bn), lambda i, j, kk: (kk, j))]
+    args = [x_q, w_q]
     sparse = occupancy is not None
     if sparse:
         assert occupancy.shape == (1, OCC_LANES), occupancy.shape
-        occupancy = occupancy.astype(jnp.int32)
+        specs.append(spec((1, OCC_LANES), lambda i, j, kk: (0, 0)))
+        args.append(occupancy.astype(jnp.int32))
+    epilogue = mult is not None
+    scratch = []
+    if epilogue:
+        out_steps = num_steps if out_steps is None else out_steps
+        out_level = (1 << out_steps) - 1 if out_level is None else out_level
+        assert out_level <= 255, (
+            "packed uint8 epilogue requires out_level <= 255")
+        if bias is None:
+            bias = jnp.zeros((1, n), jnp.int32)
+        assert bias.shape == (1, n) and mult.shape == (1, n), (
+            bias.shape, mult.shape)
+        row = spec((1, bn), lambda i, j, kk: (0, j))
+        specs += [row, row]
+        args += [bias, mult.astype(jnp.float32)]
+        scratch = [pltpu.VMEM((bm, bn), jnp.int32)]
 
-    if mult is None:
-        if plane_parallel:
-            kernel = functools.partial(
-                radix_matmul_plane_sparse_kernel if sparse
-                else radix_matmul_plane_kernel,
-                num_steps=num_steps, periods=periods, mxu_dtype=mxu_dtype)
-        elif sparse:
-            kernel = functools.partial(
-                radix_matmul_sparse_kernel, num_steps=num_steps,
-                method=method, periods=periods, mxu_dtype=mxu_dtype)
-        else:
-            kernel = functools.partial(
-                radix_matmul_kernel, num_steps=num_steps, method=method,
-                periods=periods, mxu_dtype=mxu_dtype)
-        if sparse:
-            in_specs = [x_spec, w_spec, occ_spec]
-            args = (x_q, w_q, occupancy)
-        else:
-            in_specs = [x_spec, w_spec]
-            args = (x_q, w_q)
-        return pl.pallas_call(
-            kernel,
-            grid=grid,
-            in_specs=in_specs,
-            out_specs=o_spec,
-            out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
-            interpret=interpret,
-        )(*args)
-
-    out_steps = num_steps if out_steps is None else out_steps
-    out_level = (1 << out_steps) - 1 if out_level is None else out_level
-    assert out_level <= 255, "packed uint8 epilogue requires out_level <= 255"
-    if bias is None:
-        bias = jnp.zeros((1, n), jnp.int32)
-    assert bias.shape == (1, n) and mult.shape == (1, n), (bias.shape,
-                                                          mult.shape)
-    if plane_parallel:
-        row_spec = pl.BlockSpec((1, bn), lambda i, j, kk, t: (0, j))
-        if sparse:
-            kernel = functools.partial(
-                radix_matmul_plane_sparse_epilogue_kernel,
-                num_steps=num_steps, out_level=out_level, periods=periods,
-                out_grid=out_grid, mxu_dtype=mxu_dtype)
-            in_specs = [x_spec, w_spec, occ_spec, row_spec, row_spec]
-            args = (x_q, w_q, occupancy, bias, mult.astype(jnp.float32))
-        else:
-            kernel = functools.partial(
-                radix_matmul_plane_epilogue_kernel,
-                num_steps=num_steps, out_level=out_level, periods=periods,
-                out_grid=out_grid, mxu_dtype=mxu_dtype)
-            in_specs = [x_spec, w_spec, row_spec, row_spec]
-            args = (x_q, w_q, bias, mult.astype(jnp.float32))
-    else:
-        row_spec = pl.BlockSpec((1, bn), lambda i, j, kk: (0, j))
-        if sparse:
-            kernel = functools.partial(
-                radix_matmul_sparse_epilogue_kernel, num_steps=num_steps,
-                method=method, out_level=out_level, periods=periods,
-                out_grid=out_grid, mxu_dtype=mxu_dtype)
-            in_specs = [x_spec, w_spec, occ_spec, row_spec, row_spec]
-            args = (x_q, w_q, occupancy, bias, mult.astype(jnp.float32))
-        else:
-            kernel = functools.partial(
-                radix_matmul_epilogue_kernel, num_steps=num_steps,
-                method=method, out_level=out_level, periods=periods,
-                out_grid=out_grid, mxu_dtype=mxu_dtype)
-            in_specs = [x_spec, w_spec, row_spec, row_spec]
-            args = (x_q, w_q, bias, mult.astype(jnp.float32))
+    kernel = functools.partial(
+        _matmul_kernel, num_steps=num_steps, method=method, periods=periods,
+        out_level=out_level, out_grid=out_grid, mxu_dtype=mxu_dtype,
+        sparse=sparse, epilogue=epilogue, plane_parallel=plane_parallel)
     return pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=in_specs,
-        out_specs=o_spec,
-        out_shape=jax.ShapeDtypeStruct((m, n), jnp.uint8),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
+        in_specs=specs,
+        out_specs=spec((bm, bn), lambda i, j, kk: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((m, n),
+                                       jnp.uint8 if epilogue else jnp.int32),
+        scratch_shapes=scratch,
         interpret=interpret,
     )(*args)

@@ -28,7 +28,6 @@ from repro.lm import model as M
 from repro.lm.config import ArchConfig, SHAPE_CELLS, ShapeCell
 from repro.parallel import sharding as SH
 from repro.train import optim as optim_lib
-from repro import compat
 
 __all__ = ["defined_cells", "cell_matrix", "make_batch_abstract",
            "lower_cell", "model_flops"]
@@ -122,7 +121,7 @@ def lower_cell(arch: str, cell_name: str, mesh: Mesh, *,
                   "opt": SH.opt_state_specs(pspecs, state_abs["opt"], mesh),
                   "step": P()}
         step_fn = M.make_train_step(cfg, mesh, opt)
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(
                 step_fn,
                 in_shardings=(SH.shardings(sspecs, mesh),
@@ -137,7 +136,7 @@ def lower_cell(arch: str, cell_name: str, mesh: Mesh, *,
                                max_len=cell.seq_len)
         cache_abs = M.abstract_cache(cfg, cell.global_batch, cell.seq_len)
         cspecs = SH.cache_specs(cache_abs, cfg, mesh)
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(
                 lambda params, batch: fn(params, batch),
                 in_shardings=(SH.shardings(pspecs, mesh),
@@ -156,7 +155,7 @@ def lower_cell(arch: str, cell_name: str, mesh: Mesh, *,
         P(dp, None, None) if cfg.embedding_inputs else P(dp, None),
         tok_shape, mesh)
     fn = functools.partial(M.decode_step, cfg=cfg, mesh=mesh)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(
             lambda params, caches, tokens, pos: fn(params, caches, tokens, pos),
             in_shardings=(SH.shardings(pspecs, mesh),

@@ -26,7 +26,6 @@ from typing import Dict, Optional
 
 import numpy as np
 
-from repro import compat
 from repro.launch import hlo_analysis
 
 __all__ = ["HW", "RooflineReport", "roofline", "format_row"]
@@ -87,7 +86,7 @@ def roofline(arch: str, cell: str, mesh_name: str, chips: int,
              compiled, model_flops: float, hw: HW = HW(),
              int8: bool = False) -> RooflineReport:
     cost = hlo_analysis.analyze(compiled.as_text())
-    ca = compat.cost_analysis(compiled) or {}
+    ca = compiled.cost_analysis() or {}
     mem = compiled.memory_analysis()
     mem_d = None
     if mem is not None:

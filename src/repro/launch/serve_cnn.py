@@ -50,6 +50,7 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import importlib
+import sys
 import time
 from typing import Callable, List, Optional, Sequence, Tuple
 
@@ -59,6 +60,7 @@ import numpy as np
 
 from repro import api
 from repro.core import conversion, engine
+from repro.launch import compile_cache
 from repro.runtime import resilience
 
 __all__ = [
@@ -724,9 +726,12 @@ def _parse_args(argv: Optional[Sequence[str]] = None) -> argparse.Namespace:
     return args
 
 
-def main(argv: Optional[Sequence[str]] = None) -> None:
+def main(argv: Optional[Sequence[str]] = None) -> int:
+    """Serve one synthetic request stream; returns the exit status — 1
+    when any ticket did not resolve with logits, else 0."""
     args = _parse_args(argv)
     buckets = args.bucket_ladder
+    cache_dir = compile_cache.enable()
     if args.auto:
         static, params, item, calib = build_float_net(
             args.arch, smoke=args.smoke, pool_mode=args.pool_mode,
@@ -751,8 +756,11 @@ def main(argv: Optional[Sequence[str]] = None) -> None:
         server = CNNServer(qnet, item, buckets=buckets, backend=backend,
                            dataflow=args.dataflow,
                            data_parallel=args.data_parallel)
+    dev = jax.devices()[0]
     print(f"[serve_cnn] {args.arch} {spec} backend={backend} item={item} "
-          f"buckets={buckets} devices={len(jax.devices())}")
+          f"buckets={buckets} platform={dev.platform} "
+          f"device_kind={dev.device_kind} devices={len(jax.devices())} "
+          f"compile_cache={cache_dir}")
     t0 = time.monotonic()
     server.warmup()
     print(f"[serve_cnn] warmed {len(buckets)} bucket plans in "
@@ -787,7 +795,13 @@ def main(argv: Optional[Sequence[str]] = None) -> None:
           f"retried={stats['retried']} quarantined={stats['quarantined']} "
           f"degraded_flushes={stats['degraded_flushes']} "
           f"failures={stats['failures']}")
+    if len(ok) < len(tickets):
+        print(f"[serve_cnn] FAILED: {len(tickets) - len(ok)} of "
+              f"{len(tickets)} requests did not resolve with logits",
+              file=sys.stderr)
+        return 1
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

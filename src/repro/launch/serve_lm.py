@@ -44,6 +44,7 @@ import numpy as np
 
 from repro import api
 from repro.configs import LM_ARCHS, get_config
+from repro.launch import compile_cache
 from repro.launch.serve_cnn import MicroBatchQueue, Ticket, _percentiles
 from repro.lm import model as lm_model
 from repro.runtime import resilience
@@ -226,6 +227,7 @@ def _parse_args(argv: Optional[Sequence[str]] = None) -> argparse.Namespace:
 
 def main(argv: Optional[Sequence[str]] = None) -> None:
     args = _parse_args(argv)
+    compile_cache.enable()
     t0 = time.monotonic()
     server = LMServer(
         args.arch, smoke=args.smoke, batch=args.batch,

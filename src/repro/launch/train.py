@@ -30,7 +30,6 @@ from repro.parallel import sharding as SH
 from repro.runtime.straggler import StragglerMonitor
 from repro.train import checkpoint as ckpt_lib
 from repro.train import optim as optim_lib
-from repro import compat
 
 __all__ = ["train_loop", "main"]
 
@@ -64,7 +63,7 @@ def train_loop(cfg, mesh, *, steps: int, batch_size: int, seq_len: int,
     bshard = SH.shardings(SH.batch_specs(
         jax.eval_shape(lambda: {"tokens": np.zeros((batch_size, seq_len + 1), np.int32)}),
         cfg, mesh), mesh)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         jstep = jax.jit(step_fn, in_shardings=(sshard, bshard),
                         out_shardings=(sshard, None), donate_argnums=(0,))
 

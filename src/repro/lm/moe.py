@@ -41,7 +41,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.lm.config import ArchConfig, MoEConfig
 
 __all__ = ["moe_ffn", "router_aux_loss", "pick_impl", "dp_axes"]
@@ -201,7 +200,7 @@ def _moe_ep_psum(x, p, cfg: ArchConfig, mesh: Mesh):
     in_specs = (P(dp, None, None), P(None, None),
                 P("model", dp, None), P("model", dp, None), P("model", dp, None))
     out_specs = (P(dp, None, None), P())
-    y, aux = shard_map(body, mesh=mesh, in_specs=in_specs,
+    y, aux = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
                        out_specs=out_specs, check_vma=False)(
         x, p["router"], p["w_gate"], p["w_up"], p["w_down"])
     return y, aux
@@ -243,7 +242,7 @@ def _moe_ep_a2a(x, p, cfg: ArchConfig, mesh: Mesh):
     in_specs = (P(dp, "model", None), P(None, None),
                 P("model", dp, None), P("model", dp, None), P("model", dp, None))
     out_specs = (P(dp, "model", None), P())
-    y, aux = shard_map(body, mesh=mesh, in_specs=in_specs,
+    y, aux = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
                        out_specs=out_specs, check_vma=False)(
         x, p["router"], p["w_gate"], p["w_up"], p["w_down"])
     return y, aux
@@ -273,7 +272,7 @@ def _moe_tp(x, p, cfg: ArchConfig, mesh: Mesh):
     in_specs = (P(dp, None, None), P(None, None),
                 P(None, dp, "model"), P(None, dp, "model"), P(None, "model", dp))
     out_specs = (P(dp, None, None), P())
-    y, aux = shard_map(body, mesh=mesh, in_specs=in_specs,
+    y, aux = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
                        out_specs=out_specs, check_vma=False)(
         x, p["router"], p["w_gate"], p["w_up"], p["w_down"])
     return y, aux

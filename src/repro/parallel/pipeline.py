@@ -26,7 +26,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
 
 __all__ = ["gpipe"]
 
@@ -98,7 +97,7 @@ def gpipe(block_fn: Callable, mesh: Mesh, axis: str = "pod"):
         in_specs = (jax.tree.map(lambda _: P(axis), stacked_params),
                     P(*([None] * x_micro.ndim)))
         out_specs = P(*([None] * x_micro.ndim))
-        return shard_map(stage_body, mesh=mesh, in_specs=in_specs,
+        return jax.shard_map(stage_body, mesh=mesh, in_specs=in_specs,
                          out_specs=out_specs, check_vma=False)(
             stacked_params, x_micro)
 

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _nets import trained_lenet
 from repro import api
 from repro.core import conversion
 from repro.models import fang, lenet
@@ -193,10 +194,9 @@ class TestRateEndToEnd:
         bias/multiplier/logit folding, not just quantize — regression for
         scale only being applied on the activation side (which mis-scaled
         biases 2x and zeroed every logit)."""
-        static, params, input_hw = lenet.make(pool_mode="avg",
-                                              width_mult=0.25)
-        calib = jnp.asarray(RNG.uniform(0, 1, (16,) + input_hw),
-                            jnp.float32)
+        # a trained net: argmax agreement is meaningful only where the
+        # float decisions have margins (tests/_nets.py)
+        static, params, _, calib = trained_lenet("avg", calib_batch=16)
         ref = np.asarray(
             conversion.float_forward(static, params, calib)).argmax(-1)
         spec = api.RateEncoding(31, scale=2.0)

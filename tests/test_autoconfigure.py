@@ -1,14 +1,17 @@
 """autoconfigure: lattice legality, constraints, provenance, surfaces.
 
-Runs the planner once on the LeNet-5 smoke build (module fixture) and
-probes the searched plan from every surface: the search result itself,
-``plan.compile`` -> Executable, ``api.autoconfigure``,
-``Accelerator.compile(auto=...)`` and the serve_cnn CLI validation.
+Runs the planner once on the LeNet-5 smoke build (module fixture,
+briefly trained so accuracy against the float reference is meaningful —
+tests/_nets.py) and probes the searched plan from every surface: the
+search result itself, ``plan.compile`` -> Executable,
+``api.autoconfigure``, ``Accelerator.compile(auto=...)`` and the
+serve_cnn CLI validation.
 """
 
 import numpy as np
 import pytest
 
+from _nets import trained_lenet
 from repro import api
 from repro.core import conversion
 from repro.launch import serve_cnn
@@ -21,8 +24,7 @@ KW = dict(accuracy_floor=FLOOR, latency_slo_us=SLO,
 
 @pytest.fixture(scope="module")
 def lenet_net():
-    return serve_cnn.build_float_net("lenet5", smoke=True, pool_mode="avg",
-                                     calib_batch=32, seed=0)
+    return trained_lenet("avg", calib_batch=32)
 
 
 @pytest.fixture(scope="module")
@@ -83,8 +85,7 @@ def test_summary_and_to_dict(plan):
 
 
 def test_or_pooling_rejects_rate_and_ttfs_at_spec_level():
-    static, params, item, calib = serve_cnn.build_float_net(
-        "lenet5", smoke=True, pool_mode="or", calib_batch=8, seed=0)
+    static, params, item, calib = trained_lenet("or", calib_batch=8)
     p = search.autoconfigure((static, params), item, calib=calib,
                              accuracy_floor=0.01, t_range=(3,), units=(2,))
     spec_level = {c.spec.name: c for c in p.candidates if c.backend == "-"}

@@ -9,6 +9,7 @@ lowerings, and the end-to-end ops.radix_matmul(autotune=True) path.
 
 import json
 
+import jax
 import numpy as np
 import pytest
 
@@ -41,29 +42,54 @@ class TestKernelConfig:
             KernelConfig(impl="cuda")
         with pytest.raises(ValueError):
             KernelConfig(mxu_dtype="int4")
+        with pytest.raises(ValueError):
+            KernelConfig(mxu_dtype="int32")
 
     def test_default_is_untuned_heuristic(self):
-        """The first candidate everywhere: today's 128-tile int32 path."""
+        """The first candidate everywhere: the 128-tile Pallas path on the
+        int8 lowering (the TPU compiler refuses int32 x int32 dots)."""
         cfg = KernelConfig()
-        assert (cfg.impl, cfg.mxu_dtype) == ("pallas", "int32")
+        assert (cfg.impl, cfg.mxu_dtype) == ("pallas", "int8")
         assert (cfg.bm, cfg.bk, cfg.bn, cfg.bco) == (128, 128, 128, 128)
         assert not cfg.plane_parallel
 
 
 class TestExactLowering:
     def test_int32_always_exact(self):
-        assert exact_lowering("int32", max_operand=255, k_contract=1 << 20,
+        """int32 is no lowering any more (the TPU refuses int32 x int32
+        dots); int8 took its place as the always-exact default."""
+        assert "int32" not in at.MXU_DTYPES
+        with pytest.raises(ValueError):
+            exact_lowering("int32", max_operand=255, k_contract=1 << 20,
+                           method="fused")
+        assert exact_lowering("int8", max_operand=255, k_contract=1 << 20,
                               method="fused")
 
     def test_int8_operand_bound(self):
-        """int8 inputs hold values <= 127: bit planes always fit, packed
-        fused operands only while the level fits 7 bits (T <= 7)."""
+        """int8 holds values <= 127: bit planes and T <= 7 packed levels
+        go in whole; wider fused operands are sliced into 7-bit pieces,
+        so the lowering stays exact — checked here against int32 math."""
+        from repro.kernels.radix_matmul import int8_contract, mxu_dot
         assert exact_lowering("int8", max_operand=1, k_contract=4096,
                               method="bitserial")
         assert exact_lowering("int8", max_operand=127, k_contract=4096,
                               method="fused")
-        assert not exact_lowering("int8", max_operand=255, k_contract=64,
-                                  method="fused")
+        rng = np.random.default_rng(3)
+        w = rng.integers(-128, 128, (64, 16)).astype(np.int8)
+        want_w = w.astype(np.int64)
+        for bits in (7, 8, 12):
+            a = rng.integers(0, 1 << bits, (8, 64)).astype(np.int32)
+            got = np.asarray(mxu_dot(a, w, "int8", a_bits=bits))
+            np.testing.assert_array_equal(got, a.astype(np.int64) @ want_w)
+        # both operands wide (attention's qq . kq): slices on each side
+        a = rng.integers(0, 256, (4, 32)).astype(np.int32)
+        b = rng.integers(0, 1024, (32, 8)).astype(np.int32)
+        got = np.asarray(int8_contract(
+            lambda x, y: jax.lax.dot_general(
+                x, y, (((1,), (0,)), ((), ())),
+                preferred_element_type=jax.numpy.int32),
+            a, b, a_bits=8, b_bits=10))
+        np.testing.assert_array_equal(got, a.astype(np.int64) @ b)
 
     def test_f32_partial_sum_bound(self):
         """f32 accumulates exactly below 2^24; the guard keeps the worst
@@ -159,13 +185,15 @@ class TestCandidates:
         assert any(c.plane_parallel for c in bits)
 
     def test_only_exact_lowerings_offered(self):
-        """T=8 packed fused operands overflow int8 -> no int8 candidate."""
-        cands = matmul_candidates(64, 64, 64, _sched(T=8), "fused",
+        """f32 is offered only under its mantissa bound; int8 (sliced
+        when the operand is wider than int8) always is."""
+        cands = matmul_candidates(64, 1 << 16, 64, _sched(T=8), "fused",
                                   interpret=False)
-        assert not any(c.mxu_dtype == "int8" for c in cands)
+        assert not any(c.mxu_dtype == "f32" for c in cands)
+        assert any(c.mxu_dtype == "int8" for c in cands)
         cands4 = matmul_candidates(64, 64, 64, _sched(T=4), "fused",
                                    interpret=False)
-        assert any(c.mxu_dtype == "int8" for c in cands4)
+        assert {c.mxu_dtype for c in cands4} == {"int8", "f32"}
 
     def test_no_duplicates(self):
         cands = matmul_candidates(8, 16, 8, _sched(), "bitserial",
@@ -258,19 +286,19 @@ class TestCache:
 class TestTune:
     def _candidates(self):
         return [KernelConfig(),
-                KernelConfig(impl="xla", mxu_dtype="int32"),
+                KernelConfig(impl="xla", mxu_dtype="int8"),
                 KernelConfig(impl="xla", mxu_dtype="f32")]
 
     def test_deterministic_winner_under_fake_timer(self):
         cache = AutotuneCache(None)
-        times = {"pallas/int32": 30.0, "xla/int32": 10.0, "xla/f32": 20.0}
+        times = {"pallas/int8": 30.0, "xla/int8": 10.0, "xla/f32": 20.0}
 
         def build(cfg):
             return lambda: f"{cfg.impl}/{cfg.mxu_dtype}"
 
         win = tune(("k", 1), self._candidates(), build, cache=cache,
                    timer=lambda thunk: times[thunk()])
-        assert win == KernelConfig(impl="xla", mxu_dtype="int32")
+        assert win == KernelConfig(impl="xla", mxu_dtype="int8")
         assert cache.stats.sweeps == 1
 
     def test_tie_breaks_by_candidate_order(self):
@@ -293,6 +321,9 @@ class TestTune:
         win = tune(("k", 3), self._candidates(), build, cache=cache,
                    timer=lambda thunk: 1.0)
         assert win.impl == "xla"
+        # skipped, but not in silence: counted, first error kept
+        assert cache.stats.skipped == 1
+        assert "illegal tile" in cache.stats.first_error
 
     def test_all_failing_raises(self):
         cache = AutotuneCache(None)

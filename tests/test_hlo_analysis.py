@@ -25,7 +25,7 @@ def test_scan_trip_count_multiplies_flops():
     assert N * one <= cost.flops <= N * one * 1.2, (cost.flops, N * one)
     assert any(t == N for _, t in cost.loops), cost.loops
     # raw cost_analysis counts the body once — the analyzer must exceed it
-    raw = compat.cost_analysis(c)["flops"]
+    raw = c.cost_analysis()["flops"]
     assert cost.flops > 3 * raw
 
 
@@ -54,7 +54,7 @@ def test_collective_bytes_ring_model():
         return jax.lax.with_sharding_constraint(
             x, NamedSharding(mesh, P(None, None))).sum()
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         c = jax.jit(f, in_shardings=NamedSharding(mesh, P("model", None))) \
             .lower(jax.ShapeDtypeStruct((64, 32), jnp.float32)).compile()
     cost = HA.analyze(c.as_text())

@@ -45,7 +45,7 @@ def _setup(num_experts=8, top_k=2, d=64, f=96, B=4, S=16, cf=8.0, impl="auto"):
 def test_distributed_matches_ref_generous_capacity(mesh, impl):
     cfg, p, x = _setup(impl=impl)
     y_ref, _ = moe_lib._moe_ref(x, p, cfg)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         y, aux = jax.jit(lambda x, p: moe_lib.moe_ffn(x, p, cfg, mesh))(x, p)
     np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
                                rtol=1e-5, atol=1e-5)
@@ -57,7 +57,7 @@ def test_capacity_drops_bounded(mesh):
     in aggregate (relative Frobenius error bounded)."""
     cfg, p, x = _setup(cf=1.0, impl="ep_psum")
     y_ref, _ = moe_lib._moe_ref(x, p, cfg)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         y, _ = jax.jit(lambda x, p: moe_lib.moe_ffn(x, p, cfg, mesh))(x, p)
     rel = float(jnp.linalg.norm(y - y_ref) / jnp.linalg.norm(y_ref))
     assert rel < 0.6, rel
@@ -82,7 +82,7 @@ def test_grads_flow_through_dispatch(mesh):
         y, aux = moe_lib.moe_ffn(x, p, cfg, mesh)
         return jnp.sum(y ** 2) + 0.01 * aux
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         g = jax.jit(jax.grad(loss))(p)
     for k, v in g.items():
         assert bool(jnp.isfinite(v).all()), k

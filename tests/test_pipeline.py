@@ -32,7 +32,7 @@ def test_gpipe_matches_sequential(stages, n_micro):
               "b": jnp.zeros((L, D))}
     x = jax.random.normal(jax.random.PRNGKey(1), (n_micro, mb, D))
     ref = jax.vmap(lambda xm: _seq(params, xm))(x)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         out = jax.jit(gpipe(_block, mesh, axis="pod"))(params, x)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-6, atol=1e-6)
@@ -51,7 +51,7 @@ def test_gpipe_differentiable():
     def loss_seq(p):
         return jnp.sum(jax.vmap(lambda xm: _seq(p, xm))(x) ** 2)
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         g_pp = jax.jit(jax.grad(loss_pp))(params)
     g_seq = jax.grad(loss_seq)(params)
     jax.tree.map(lambda a, b: np.testing.assert_allclose(

@@ -108,8 +108,8 @@ def test_raw_cost_analysis_passthrough():
         ca={"flops": 999.0, "bytes accessed": 888.0}))
     assert r.raw_flops == 999.0
     assert r.raw_bytes == 888.0
-    # list-wrapped cost_analysis (older jax) is normalized by compat
-    r2 = _report(compiled=FakeCompiled(ca=[{"flops": 7.0}]))
+    # a cost analysis without the bytes entry leaves raw_bytes unset
+    r2 = _report(compiled=FakeCompiled(ca={"flops": 7.0}))
     assert r2.raw_flops == 7.0
     assert r2.raw_bytes is None
 

@@ -232,7 +232,7 @@ def test_elastic_training_continues_across_topologies(tmp_path):
 
     # reference: 4 steps on mesh A only
     mesh_a = make_test_mesh(data=2, model=4)
-    with compat.set_mesh(mesh_a):
+    with jax.set_mesh(mesh_a):
         st = place(state0, mesh_a)
         step_a = jax.jit(make_step(mesh_a))
         for _ in range(4):
@@ -240,14 +240,14 @@ def test_elastic_training_continues_across_topologies(tmp_path):
     ref_loss = float(m_ref["loss"])
 
     # elastic: 2 steps on A -> checkpoint -> restore on B (4x2) -> 2 steps
-    with compat.set_mesh(mesh_a):
+    with jax.set_mesh(mesh_a):
         st = place(state0, mesh_a)
         for _ in range(2):
             st, _ = step_a(st, batch)
     ckpt_lib.save(str(tmp_path), 2, st)
 
     mesh_b = make_test_mesh(data=4, model=2)
-    with compat.set_mesh(mesh_b):
+    with jax.set_mesh(mesh_b):
         st_b = place(jax.tree.map(np.asarray, st), mesh_b)  # structure donor
         restored, _ = ckpt_lib.restore(str(tmp_path), 2, st_b)
         step_b = jax.jit(make_step(mesh_b))

@@ -207,6 +207,42 @@ def test_build_qnet_registry_archs():
         assert qnet.num_steps == 3
 
 
+@pytest.mark.parametrize("fail", [False, True], ids=["healthy", "failing"])
+def test_main_reports_device_and_exit_status(fail, monkeypatch, capsys):
+    """The CLI names the device it ran on and exits non-zero when any
+    request did not resolve with logits (here: every infer raises, so
+    every ticket is quarantined)."""
+    from repro.launch import compile_cache
+
+    monkeypatch.setattr(compile_cache, "enable", lambda: "off")
+    if fail:
+        def boom(self, x):
+            raise RuntimeError("device lost")
+
+        monkeypatch.setattr(serve_cnn.CNNServer, "infer", boom)
+    rc = serve_cnn.main(["--arch", "lenet5", "--smoke", "--requests", "3",
+                         "--max-request", "2", "--buckets", "1,2",
+                         "--retries", "0"])
+    out, err = capsys.readouterr()
+    assert "platform=cpu" in out and "device_kind=" in out
+    assert rc == (1 if fail else 0)
+    assert ("FAILED: 3 of 3 requests" in err) == fail
+
+
+def test_compile_cache_default_is_a_fixed_ignored_checkout_dir():
+    """Without ``JAX_COMPILATION_CACHE_DIR`` the entry points cache at a
+    fixed ``<checkout>/.jax_cache`` that git never commits (the helper
+    itself is never called from tests)."""
+    import pathlib
+
+    from repro.launch import compile_cache
+
+    root = pathlib.Path(__file__).resolve().parents[1]
+    assert compile_cache.DEFAULT_DIR == root / ".jax_cache"
+    assert ".jax_cache/" in (root / ".gitignore").read_text().split()
+    assert compile_cache.ENV_VAR == "JAX_COMPILATION_CACHE_DIR"
+
+
 # ---------------------------------------------------------------------------
 # serve_bench emits a well-formed BENCH_serve.json.
 # ---------------------------------------------------------------------------

@@ -78,7 +78,7 @@ def test_sharded_train_matches_single_device(arch, mesh):
                   "opt": SH.opt_state_specs(
                       pspecs, jax.eval_shape(lambda: state["opt"]), mesh),
                   "step": P()}
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             st = jax.device_put(state, SH.shardings(sspecs, mesh))
             jstep = jax.jit(step, in_shardings=(SH.shardings(sspecs, mesh),
                                                 SH.shardings(SH.batch_specs(
@@ -108,7 +108,7 @@ def test_sharded_decode_matches_single_device(mesh):
                                  max_len=16)
     lg_1, _ = M.decode_step(params, caches_1, tok[:, -1:], jnp.int32(8),
                             cfg, None)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         last_m, caches_m = jax.jit(
             lambda p, b: M.prefill(p, b, cfg, mesh, max_len=16))(
                 params, {"tokens": tok})
