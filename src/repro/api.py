@@ -52,10 +52,12 @@ migration table).
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import conversion, engine
 from repro.core.encoding import (
@@ -70,6 +72,7 @@ from repro.core.encoding import (
     support_matrix_markdown,
 )
 from repro.core.conversion import convert
+from repro.runtime.trace import span
 
 __all__ = [
     "EncodingSpec",
@@ -284,12 +287,15 @@ class Executable:
 
         Any ``n``: pads up to the nearest bucket / chunks by the top
         bucket.  Raises ``ValueError`` when the item shape of ``x`` does
-        not match the executable's compiled ``item_shape``."""
-        x = jnp.asarray(x, jnp.float32)
-        if tuple(x.shape[1:]) != self.item_shape:
+        not match the executable's compiled ``item_shape``.  Host input
+        moves to the device here, once, in the ``exe.to_device`` span."""
+        shape = np.shape(x)
+        if tuple(shape[1:]) != self.item_shape:
             raise ValueError(
-                f"request item shape {tuple(x.shape[1:])} != executable's "
+                f"request item shape {tuple(shape[1:])} != executable's "
                 f"{self.item_shape}")
+        with span("exe.to_device", images=shape[0], bytes=4 * math.prod(shape)):
+            x = jnp.asarray(x, jnp.float32)
         return self._cache.run(self.qnet, x)
 
     def warmup(self) -> "Executable":

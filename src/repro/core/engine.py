@@ -40,9 +40,10 @@ from __future__ import annotations
 
 import dataclasses
 import math
+import re
 import warnings
 import weakref
-from typing import Callable, List, Literal, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Literal, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -50,6 +51,7 @@ import numpy as np
 
 from repro import compat
 from repro.core import conversion, encoding, layers
+from repro.runtime.trace import span
 
 __all__ = ["run", "compile_plan", "CompiledPlan", "PlanLayerInfo",
            "PlanCache", "PlanCacheStats", "DEFAULT_BUCKETS",
@@ -233,10 +235,16 @@ class CompiledPlan:
     unrolled into one XLA program at trace time).
 
     Every call also runs the plane-occupancy prepass (DESIGN.md §8): the
-    number of globally-empty spike planes each kernel layer skipped
-    accumulates lazily (a device scalar — no sync until
-    :meth:`plane_stats` is read) against the static per-call plane-pass
-    budget ``plane_passes_per_call``.
+    number of globally-empty spike planes each kernel layer skipped comes
+    back as a device scalar per call, kept on the host unread (no sync
+    and no device program of its own) and summed when :meth:`plane_stats`
+    is read, against the static per-call plane-pass budget
+    ``plane_passes_per_call``.
+
+    The jitted program is named ``plan_b<bucket>`` and each layer runs
+    under a ``jax.named_scope`` (``input``, ``conv1``…, ``pool1``…,
+    ``flatten``, ``fc1``…, ``output``): :meth:`layer_map` reads which
+    instruction of the compiled program belongs to which layer.
     """
 
     input_shape: Tuple[int, ...]
@@ -247,8 +255,8 @@ class CompiledPlan:
     _params: list = dataclasses.field(repr=False)
     data_parallel: int = 1         # batch shards (shard_map over devices)
     plane_passes_per_call: int = 0  # static: sum of in_bits*periods/layer
-    _skipped: Optional[jax.Array] = dataclasses.field(default=None,
-                                                      repr=False)
+    _skips: list = dataclasses.field(default_factory=list, repr=False)
+    _skipped: int = dataclasses.field(default=0, repr=False)
     _calls: int = dataclasses.field(default=0, repr=False)
     tuned_tiles: List[dict] = dataclasses.field(default_factory=list)
     """Per kernel layer: the resolved execution strategy — layer name +
@@ -257,33 +265,52 @@ class CompiledPlan:
     from an autotune sweep or is the untuned default."""
 
     def __call__(self, x: jax.Array) -> jax.Array:
-        out, skipped = self._fn(self._params, x)
-        # lazy device-side accumulation: no host sync on the hot path.
+        with span("plan.dispatch", bucket=self.input_shape[0]):
+            out, skipped = self._fn(self._params, x)
+        # the skip count stays on the device, unread, until plane_stats():
+        # no host sync and no device program beyond the plan per call.
         # Under an outer jax transformation `skipped` is a tracer — storing
         # it would leak it (and poison later eager calls), so the counters
         # simply don't accumulate for traced calls; the plan stays pure.
         if not isinstance(skipped, jax.core.Tracer):
-            self._skipped = skipped if self._skipped is None \
-                else self._skipped + skipped
+            self._skips.append(skipped)
             self._calls += 1
+            if len(self._skips) >= 2 * SKIP_FOLD:
+                # bound the list: the oldest calls finished long ago
+                self._fold(SKIP_FOLD)
         return out
+
+    def _fold(self, n: int) -> None:
+        """Sum the oldest ``n`` per-call skip counts into the host total
+        (one copy of the small arrays to the host)."""
+        done, self._skips = self._skips[:n], self._skips[n:]
+        self._skipped += sum(int(s.sum()) for s in jax.device_get(done))
 
     def plane_stats(self) -> dict:
         """Sparsity-prepass counters: plane passes skipped (all-zero
         spike planes — bitserial early-exits, fused masked lanes) vs the
         static schedule total across every call so far.  Reading this
-        syncs the lazily-accumulated device scalar."""
-        skipped = 0 if self._skipped is None else int(
-            np.asarray(self._skipped).sum())
-        return {"plane_passes_skipped": skipped,
+        waits for the calls whose counts are still on the device."""
+        self._fold(len(self._skips))
+        return {"plane_passes_skipped": self._skipped,
                 "plane_passes_total": self._calls * self.plane_passes_per_call}
 
     def reset_plane_stats(self) -> None:
         """Zero the sparsity counters (warmup runs all-zero batches that
         skip nearly every plane — left in, they would swamp the stats of
         real traffic)."""
-        self._skipped = None
-        self._calls = 0
+        self._skips, self._skipped, self._calls = [], 0, 0
+
+    def layer_map(self) -> Dict[str, str]:
+        """HLO instruction name -> layer scope (``input``, ``conv1``,
+        ``pool1``, ``flatten``, ``fc1``, ``output``…) for every
+        instruction of this plan's compiled program whose metadata names
+        one: the names a device trace gives the plan's operations.
+        Compiles the plan ahead of time (a cache hit where the persistent
+        compilation cache holds it); call it outside a timed window."""
+        x = jax.ShapeDtypeStruct(self.input_shape, jnp.float32)
+        return layer_scopes(self._fn.lower(self._params, x).compile()
+                            .as_text())
 
     def activation_traffic(self) -> dict:
         """Modeled inter-layer activation bytes written: fused vs unfused."""
@@ -295,6 +322,31 @@ class CompiledPlan:
             "int32_write_bytes": unfused,
             "traffic_ratio": unfused / max(fused, 1),
         }
+
+
+# per-call skip counts kept on the device before the oldest are summed
+SKIP_FOLD = 64
+# a plan's layer scopes, as named_scope puts them in op_name metadata
+_LAYER_SCOPE = re.compile(r"^(?:input|output|flatten|(?:conv|pool|fc)\d+)$")
+_HLO_OP = re.compile(r'^\s*(?:ROOT )?%?([\w.\-]+) = .*\bop_name="([^"]*)"')
+# scope prefix per layer kind, numbered from 1 in network order
+_SCOPE_PREFIX = {"conv": "conv", "pool": "pool", "linear": "fc"}
+
+
+def layer_scopes(hlo_text: str) -> Dict[str, str]:
+    """Instruction name -> the first layer scope in its ``op_name``
+    metadata, over every instruction of an HLO module's text
+    (``compiled.as_text()``) that has one."""
+    out = {}
+    for line in hlo_text.splitlines():
+        m = _HLO_OP.match(line)
+        if m is None:
+            continue
+        for part in m.group(2).split("/"):
+            if _LAYER_SCOPE.match(part):
+                out[m.group(1)] = part
+                break
+    return out
 
 
 def compile_plan(
@@ -425,6 +477,8 @@ def _compile_plan_impl(
     rows = batch                   # current physical row count (batch dim)
     bits = T                       # integer bits carried by activations
     steps: List[Tuple[Callable, dict]] = []
+    scopes: List[str] = []         # each step's named_scope
+    scope_counts: dict = {}
     infos: List[PlanLayerInfo] = []
     tuned: List[dict] = []
     n_layers = len(qnet.static)
@@ -464,6 +518,11 @@ def _compile_plan_impl(
         return row, (in_bits - occ_bits.sum()) * periods
 
     for (kind, cfg), qp in zip(qnet.static, qnet.qlayers):
+        if kind in _SCOPE_PREFIX:
+            scope_counts[kind] = scope_counts.get(kind, 0) + 1
+            scopes.append(f"{_SCOPE_PREFIX[kind]}{scope_counts[kind]}")
+        else:
+            scopes.append(kind)
         if kind == "conv":
             kh, kw, cin, cout = qp["w_q"].shape
             assert cin == c_real, (cin, c_real)
@@ -771,13 +830,17 @@ def _compile_plan_impl(
     input_scale, logit_scale = qnet.input_scale, qnet.logit_scale
 
     def forward(params, x):
-        state = spec.quantize(x, input_scale)
+        with jax.named_scope("input"):
+            state = spec.quantize(x, input_scale)
         skipped = jnp.zeros((1,), jnp.int32)   # (1,): shard_map-concatable
-        for (apply, _), p in zip(steps, params):
-            state, sk = apply(state, p)
-            skipped = skipped + sk
-        return state.astype(jnp.float32) * logit_scale, skipped
+        for scope, (apply, _), p in zip(scopes, steps, params):
+            with jax.named_scope(scope):
+                state, sk = apply(state, p)
+                skipped = skipped + sk
+        with jax.named_scope("output"):
+            return state.astype(jnp.float32) * logit_scale, skipped
 
+    forward.__name__ = f"plan_b{batch}"   # the trace's XLA module name
     params = [p for _, p in steps]
     return CompiledPlan(
         input_shape=tuple(input_shape),
@@ -854,10 +917,15 @@ def _data_parallel_plan(qnet, input_shape, method, data_parallel, spec=None,
     # the per-shard skip counters — each shard ran its own prepass); no
     # collectives cross shards, so replication checking is moot (and trips
     # over pallas_call on some jax versions) -> disabled.
-    fn = jax.shard_map(inner._fn, mesh=mesh,
-                      in_specs=(P(), P("batch")),
-                      out_specs=(P("batch"), P("batch")),
-                      check_vma=False)
+    sharded = jax.shard_map(inner._fn, mesh=mesh,
+                           in_specs=(P(), P("batch")),
+                           out_specs=(P("batch"), P("batch")),
+                           check_vma=False)
+
+    def fn(params, x):
+        return sharded(params, x)
+
+    fn.__name__ = f"plan_b{batch}"
     infos = [dataclasses.replace(
         l,
         out_shape=(l.out_shape[0] * data_parallel,) + l.out_shape[1:],
@@ -1075,7 +1143,9 @@ class PlanCache:
         bucket = self.bucket_for(rem)
         tail = x[off:]
         if bucket > rem:
-            tail = jnp.pad(tail, ((0, bucket - rem),) + ((0, 0),) * len(item))
+            with span("exe.pad", bucket=bucket, rows=bucket - rem):
+                tail = jnp.pad(tail,
+                               ((0, bucket - rem),) + ((0, 0),) * len(item))
             self.stats.padded_rows += bucket - rem
         outs.append(self.plan_for(qnet, bucket, item)(tail)[:rem])
         self.stats.executions += 1

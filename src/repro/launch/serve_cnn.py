@@ -62,6 +62,7 @@ from repro import api
 from repro.core import conversion, engine
 from repro.launch import compile_cache
 from repro.runtime import resilience
+from repro.runtime.trace import span
 
 __all__ = [
     "ARCHS",
@@ -229,11 +230,14 @@ class CNNServer:
         return self.exe.stats()
 
     def infer(self, x) -> jax.Array:
-        """(n,) + item_shape float images -> (n, classes) float logits."""
-        x = jnp.asarray(x, jnp.float32)
-        if tuple(x.shape[1:]) != self.item_shape:
+        """(n,) + item_shape float images -> (n, classes) float logits.
+
+        The executable moves ``x`` to the device (the ``exe.to_device``
+        span), once."""
+        shape = np.shape(x)
+        if tuple(shape[1:]) != self.item_shape:
             raise ValueError(
-                f"request item shape {tuple(x.shape[1:])} != server's "
+                f"request item shape {tuple(shape[1:])} != server's "
                 f"{self.item_shape}")
         return self.exe(x)
 
@@ -257,6 +261,7 @@ class Ticket:
     size: int
     t_submit: float
     deadline: Optional[float] = None      # absolute clock time; None = none
+    id: int = 0                           # the queue's sequence number
     result: Optional[jax.Array] = None
     error: Optional[Exception] = None     # terminal ServeError
     latency_s: Optional[float] = None     # submit -> resolved (either way)
@@ -369,6 +374,9 @@ class MicroBatchQueue:
         self._pending: List[Tuple[np.ndarray, Ticket]] = []
         self._count = 0
         self.flushes = 0          # successful infer flushes (incl. halves)
+        # ids for the trace spans; the last flush id is the running flush's
+        self._tickets = 0
+        self._flush_ids = 0
 
     @property
     def pending_images(self) -> int:
@@ -391,35 +399,39 @@ class MicroBatchQueue:
         :class:`~repro.runtime.resilience.AdmissionError` instead of
         raising.  ``deadline_s`` (default ``default_deadline_s``) is a
         relative deadline from now; expired tickets are shed pre-flush."""
-        x = np.asarray(x, np.float32)
-        if x.ndim == len(self.server.item_shape):
-            x = x[None]
-        if tuple(x.shape[1:]) != self.server.item_shape:
-            raise ValueError(
-                f"request item shape {tuple(x.shape[1:])} != server's "
-                f"{self.server.item_shape}")
-        if x.shape[0] == 0:
-            raise ValueError("empty request (0 images)")
-        now = self.clock()
-        if deadline_s is None:
-            deadline_s = self.default_deadline_s
-        ticket = Ticket(size=x.shape[0], t_submit=now,
-                        deadline=None if deadline_s is None
-                        else now + deadline_s)
-        if not self.health.accepting:
-            return self._reject(
-                ticket, f"server draining (health={self.health.state}); "
-                "not accepting new requests")
-        if self._count + ticket.size > self.max_pending:
-            if self.admission == "flush":
-                self.flush()          # backpressure: drain to make room
-            if self._count + ticket.size > self.max_pending:
+        self._tickets += 1
+        with span("queue.submit", ticket=self._tickets) as sp:
+            x = np.asarray(x, np.float32)
+            if x.ndim == len(self.server.item_shape):
+                x = x[None]
+            if tuple(x.shape[1:]) != self.server.item_shape:
+                raise ValueError(
+                    f"request item shape {tuple(x.shape[1:])} != server's "
+                    f"{self.server.item_shape}")
+            if x.shape[0] == 0:
+                raise ValueError("empty request (0 images)")
+            sp.set_metadata(images=x.shape[0])
+            now = self.clock()
+            if deadline_s is None:
+                deadline_s = self.default_deadline_s
+            ticket = Ticket(size=x.shape[0], t_submit=now,
+                            deadline=None if deadline_s is None
+                            else now + deadline_s, id=self._tickets)
+            if not self.health.accepting:
                 return self._reject(
-                    ticket, f"queue at admission bound: {self._count} "
-                    f"pending + {ticket.size} > max_pending="
-                    f"{self.max_pending}")
-        self._pending.append((x, ticket))
-        self._count += ticket.size
+                    ticket, f"server draining (health={self.health.state}"
+                    "); not accepting new requests")
+            if self._count + ticket.size > self.max_pending:
+                if self.admission == "flush":
+                    # backpressure: drain to make room
+                    self._flush("backpressure")
+                if self._count + ticket.size > self.max_pending:
+                    return self._reject(
+                        ticket, f"queue at admission bound: {self._count} "
+                        f"pending + {ticket.size} > max_pending="
+                        f"{self.max_pending}")
+            self._pending.append((x, ticket))
+            self._count += ticket.size
         self.poll(now)
         return ticket
 
@@ -458,8 +470,11 @@ class MicroBatchQueue:
         if not self._pending:
             return False
         oldest = self._pending[0][1].t_submit
-        if self._count >= self.max_batch or now - oldest >= self.timeout_s:
-            self.flush()
+        if self._count >= self.max_batch:
+            self._flush("full")
+            return True
+        if now - oldest >= self.timeout_s:
+            self._flush("timeout")
             return True
         return False
 
@@ -467,21 +482,34 @@ class MicroBatchQueue:
         """Run everything pending; every involved ticket reaches a
         terminal state (logits, shed, or quarantined) — flush itself
         never raises on an infer fault."""
+        self._flush("explicit")
+
+    def _flush(self, trigger: str) -> None:
+        """:meth:`flush`, which ``trigger`` (``full``, ``timeout``,
+        ``backpressure`` or ``explicit``) names in its ``queue.flush``
+        span."""
         self._shed_expired(self.clock())
         if not self._pending:
             return
-        pending, self._pending, self._count = self._pending, [], 0
-        if self.health.degraded:
-            groups = self._split(pending, self.degraded_max_batch)
-        else:
-            groups = [pending]
-        for group in groups:
-            if self._run_group(group):
-                # one fault event = ONE unhealthy health sample, however
-                # many bisection sub-flushes and retries it took to
-                # isolate — a single poisoned request must degrade the
-                # server, never single-handedly drive it to draining
-                self.health.record_failure()
+        pending, images = self._pending, self._count
+        self._pending, self._count = [], 0
+        self._flush_ids += 1
+        with span("queue.flush", flush=self._flush_ids, tickets=len(pending),
+                  first_ticket=pending[0][1].id,
+                  last_ticket=pending[-1][1].id, images=images,
+                  trigger=trigger):
+            if self.health.degraded:
+                groups = self._split(pending, self.degraded_max_batch)
+            else:
+                groups = [pending]
+            for group in groups:
+                if self._run_group(group):
+                    # one fault event = ONE unhealthy health sample,
+                    # however many bisection sub-flushes and retries it
+                    # took to isolate — a single poisoned request must
+                    # degrade the server, never single-handedly drive it
+                    # to draining
+                    self.health.record_failure()
 
     @staticmethod
     def _split(pending, cap: int):
@@ -505,14 +533,18 @@ class MicroBatchQueue:
         if any infer attempt in the subtree faulted — the *caller*
         (``flush``) records at most one health failure per top-level
         group, not one per bisection level or retry attempt."""
-        batch = group[0][0] if len(group) == 1 else np.concatenate(
-            [x for x, _ in group], axis=0)
+        with span("queue.assemble", flush=self._flush_ids,
+                  bytes=sum(x.nbytes for x, _ in group)):
+            batch = group[0][0] if len(group) == 1 else np.concatenate(
+                [x for x, _ in group], axis=0)
         if self.health.degraded:
             self.counters.degraded_flushes += 1
         t0 = self.clock()
         try:
-            logits = self.server.infer(batch)
-            jax.block_until_ready(logits)
+            with span("queue.infer", flush=self._flush_ids,
+                      images=batch.shape[0]):
+                logits = self.server.infer(batch)
+                jax.block_until_ready(logits)
         except Exception as err:
             if len(group) > 1:
                 # bisecting quarantine: O(log n) re-flushes isolate one
@@ -542,8 +574,10 @@ class MicroBatchQueue:
                 return
             t0 = self.clock()
             try:
-                logits = self.server.infer(x)
-                jax.block_until_ready(logits)
+                with span("queue.infer", flush=self._flush_ids,
+                          images=ticket.size):
+                    logits = self.server.infer(x)
+                    jax.block_until_ready(logits)
             except Exception as again:
                 err = again
                 continue
@@ -558,14 +592,16 @@ class MicroBatchQueue:
         self.counters.quarantined += 1
 
     def _resolve(self, group, logits, t0: float) -> None:
-        done = self.clock()
-        self.flushes += 1
-        self.health.record_flush(done - t0)
-        off = 0
-        for x, ticket in group:
-            ticket.result = logits[off:off + x.shape[0]]
-            ticket.latency_s = done - ticket.t_submit
-            off += x.shape[0]
+        with span("queue.resolve", flush=self._flush_ids,
+                  tickets=len(group)):
+            done = self.clock()
+            self.flushes += 1
+            self.health.record_flush(done - t0)
+            off = 0
+            for x, ticket in group:
+                ticket.result = logits[off:off + x.shape[0]]
+                ticket.latency_s = done - ticket.t_submit
+                off += x.shape[0]
 
 
 # ---------------------------------------------------------------------------

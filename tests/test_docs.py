@@ -212,6 +212,19 @@ def test_serving_guide_matches_code_surface():
             f"DESIGN.md failure-mode table is missing {field.name}")
 
 
+def test_serving_guide_lists_the_trace_spans():
+    """docs/serving.md's "Tracing a server" table holds exactly the spans
+    of ``repro.runtime.trace.SPANS``, each with the arguments it
+    carries."""
+    from repro.runtime import trace
+
+    text = (REPO / "docs" / "serving.md").read_text()
+    section = text[text.index("## 7. Tracing a server"):]
+    rows = re.findall(r"^\| `([\w.]+)` \|[^|]*\| (.*) \|$", section, re.M)
+    assert {name: tuple(re.findall(r"`(\w+)`", args))
+            for name, args in rows} == trace.SPANS
+
+
 def test_ppa_guide_is_cross_linked():
     """docs/ppa.md (the planner guide) must be discoverable from the
     README and DESIGN.md §9, and is itself in DOC_FILES so its

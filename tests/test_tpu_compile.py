@@ -13,11 +13,15 @@ The topology is described inside a module fixture and nowhere else:
 only the worker that runs this file loads the TPU library.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import conversion, engine
 from repro.kernels import ops
 from repro.kernels.autotune import KernelConfig
 from repro.kernels.radix_attn import radix_decode_attn_pallas
@@ -125,3 +129,37 @@ CASES = {
 def test_compiles_for_v5e(case, one_chip):
     fn, shapes = CASES[case]()
     _compile(fn, shapes, one_chip)
+
+
+def test_lenet_plan_keeps_kernel_names_and_maps_layers(one_chip,
+                                                       monkeypatch):
+    """A whole LeNet-5 plan, compiled for the chip: its Pallas custom
+    calls keep the instruction-name prefixes a device trace is read by
+    (``radix_conv2d_pallas.``, ``radix_matmul_pallas.``), and the plan's
+    instruction -> layer map puts each of them under its own layer."""
+    from repro.models import lenet
+
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    static, params, hw = lenet.make(pool_mode="or", width_mult=0.25)
+    calib = jnp.asarray(np.random.default_rng(0).uniform(0, 1, (4,) + hw),
+                        jnp.float32)
+    qnet = conversion.convert(static, params, calib, num_steps=T)
+    plan = engine._compile_plan_impl(qnet, (8,) + tuple(hw), method="fused")
+    spec = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        plan._params)
+    x = jax.ShapeDtypeStruct((8,) + tuple(hw), jnp.float32,
+                             sharding=one_chip)
+    text = plan._fn.lower(spec, x).compile().as_text()
+    assert text.startswith("HloModule jit_plan_b8")
+    calls = re.findall(r"^\s*%([\w.\-]+) = [^\n]*\bcustom-call\(",
+                       text, re.M)
+    layers = engine.layer_scopes(text)
+    kinds = {"radix_conv2d_pallas.": "conv", "radix_matmul_pallas.": "fc"}
+    got = []
+    for name in calls:
+        prefix = [p for p in kinds if name.startswith(p)]
+        assert prefix, f"custom call {name} lost its kernel's name"
+        assert layers[name].startswith(kinds[prefix[0]]), (name, layers)
+        got.append(layers[name])
+    assert sorted(got) == ["conv1", "conv2", "conv3", "fc1", "fc2", "fc3"]
