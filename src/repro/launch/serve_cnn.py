@@ -50,6 +50,7 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import importlib
+import math
 import sys
 import time
 from typing import Callable, List, Optional, Sequence, Tuple
@@ -323,6 +324,26 @@ class MicroBatchQueue:
       smaller bucket, which also shards over fewer devices); draining
       refuses admissions until ``health.resume()``.
 
+    A group of several requests with at most ``max_batch`` images is
+    assembled into a float32 staging buffer of ``max_batch`` images that
+    the queue keeps across flushes (``staging_allocs`` counts the
+    buffers allocated), so a large batch is not written into freshly
+    faulted pages on every flush.  The batch takes the memory order of
+    the group's requests, as a new concatenation would: C order for
+    C-ordered requests, batch-minor for slices of an array that
+    ``np.asarray`` made from a TPU array.  A single request goes to the
+    server as it came; a larger group is concatenated into a new array.
+    The buffer's contract:
+
+    * it is rewritten only after the previous ``server.infer`` on it has
+      returned through ``jax.block_until_ready``;
+    * after any infer fault the queue drops it and allocates another:
+      the failed call may still read it (a transfer in flight, or a
+      zero-copy alias on CPU), and the bisection and retry sub-flushes
+      must never write memory it may read;
+    * tickets hold the logits the server returned, never views of the
+      buffer, and a server keeps nothing of the batch past ``infer``.
+
     Single-threaded and event-driven: callers drive time via
     :meth:`submit` / :meth:`poll` (``clock`` and the backoff ``sleep``
     injectable, so chaos tests are deterministic).  Latency recorded per
@@ -374,6 +395,8 @@ class MicroBatchQueue:
         self._pending: List[Tuple[np.ndarray, Ticket]] = []
         self._count = 0
         self.flushes = 0          # successful infer flushes (incl. halves)
+        self._staging: Optional[np.ndarray] = None
+        self.staging_allocs = 0   # staging buffers allocated for assembly
         # ids for the trace spans; the last flush id is the running flush's
         self._tickets = 0
         self._flush_ids = 0
@@ -533,10 +556,16 @@ class MicroBatchQueue:
         if any infer attempt in the subtree faulted — the *caller*
         (``flush``) records at most one health failure per top-level
         group, not one per bisection level or retry attempt."""
+        rows = sum(x.shape[0] for x, _ in group)
+        staged = len(group) > 1 and rows <= self.max_batch
         with span("queue.assemble", flush=self._flush_ids,
-                  bytes=sum(x.nbytes for x, _ in group)):
-            batch = group[0][0] if len(group) == 1 else np.concatenate(
-                [x for x, _ in group], axis=0)
+                  bytes=sum(x.nbytes for x, _ in group), staged=int(staged)):
+            if len(group) == 1:
+                batch = group[0][0]
+            else:
+                batch = np.concatenate(
+                    [x for x, _ in group], axis=0,
+                    out=self._staging_batch(group, rows) if staged else None)
         if self.health.degraded:
             self.counters.degraded_flushes += 1
         t0 = self.clock()
@@ -546,6 +575,9 @@ class MicroBatchQueue:
                 logits = self.server.infer(batch)
                 jax.block_until_ready(logits)
         except Exception as err:
+            # the failed call may still read the buffer (a transfer in
+            # flight, or a zero-copy alias on CPU): never write it again
+            self._staging = None
             if len(group) > 1:
                 # bisecting quarantine: O(log n) re-flushes isolate one
                 # poison request; healthy halves complete on their own
@@ -557,6 +589,32 @@ class MicroBatchQueue:
             return True
         self._resolve(group, logits, t0)
         return False
+
+    def _staging_batch(self, group, rows: int) -> np.ndarray:
+        """A ``(rows,) + item_shape`` batch at the head of the staging
+        buffer (allocated on first use and again after a fault, and kept
+        across flushes), laid out in memory as the group's first request
+        of more than one image is: the layout ``np.concatenate`` gives a
+        new batch, so the copy runs in memory order and the server gets
+        the layout it got before.  C order when every request is one
+        image, whose batch axis says nothing."""
+        if self._staging is None:
+            self._staging = np.empty(
+                self.max_batch * math.prod(self.server.item_shape),
+                np.float32)
+            self.staging_allocs += 1
+        shape = (rows,) + self.server.item_shape
+        order = list(range(len(shape)))
+        x = next((x for x, _ in group if x.shape[0] > 1), None)
+        if x is not None:
+            # axes outermost first by stride; an axis of length 1 keeps
+            # its place, its stride being arbitrary
+            by_stride = iter(sorted((a for a in order if x.shape[a] > 1),
+                                    key=lambda a: -abs(x.strides[a])))
+            order = [a if x.shape[a] == 1 else next(by_stride)
+                     for a in order]
+        return self._staging[:math.prod(shape)].reshape(
+            [shape[a] for a in order]).transpose(np.argsort(order))
 
     def _retry_single(self, item, err: Exception) -> None:
         """Bounded backoff retries for an isolated ticket; shed the

@@ -94,6 +94,150 @@ def test_queue_results_bit_exact_per_request(server):
 
 
 # ---------------------------------------------------------------------------
+# Flush assembly: the staging buffer kept across flushes.
+# ---------------------------------------------------------------------------
+
+
+class SpyServer:
+    """The module's server, keeping each batch ``infer`` was given."""
+
+    def __init__(self, server):
+        self.server = server
+        self.item_shape = server.item_shape
+        self.exe = server.exe
+        self.resilience = server.resilience
+        self.batches = []
+
+    def infer(self, x):
+        self.batches.append(x)
+        return self.server.infer(x)
+
+
+def _fresh_logits(server, reqs):
+    """Each request's rows of ``server.infer`` on a new concatenation."""
+    out = np.asarray(server.infer(np.concatenate(reqs, axis=0)))
+    offs = np.cumsum([0] + [r.shape[0] for r in reqs])
+    return [out[a:b] for a, b in zip(offs[:-1], offs[1:])]
+
+
+def test_full_flushes_reuse_one_staging_buffer_bit_exact(server):
+    clock = FakeClock()
+    spy = SpyServer(server)
+    q = serve_cnn.MicroBatchQueue(spy, max_batch=8, timeout_s=1e9,
+                                  clock=clock)
+    flushes = []
+    for sizes in ((3, 5), (2, 2, 4), (1, 6, 1), (4, 4)):
+        reqs = [_req(server, n) for n in sizes]
+        tickets = [q.submit(r) for r in reqs]     # the last fills -> flush
+        assert all(t.ok for t in tickets)
+        got = [np.asarray(t.result).copy() for t in tickets]
+        for g, want in zip(got, _fresh_logits(server, reqs)):
+            np.testing.assert_array_equal(g, want)
+        flushes.append((tickets, got))
+    assert q.flushes == 4 and q.staging_allocs == 1
+    # every batch was a C-ordered view of the one kept buffer
+    base = q._staging
+    assert all(b.base is base and b.flags.c_contiguous
+               for b in spy.batches)
+    # later flushes overwrote the buffer; earlier tickets kept their rows
+    for tickets, got in flushes:
+        for t, g in zip(tickets, got):
+            np.testing.assert_array_equal(np.asarray(t.result), g)
+
+
+@pytest.mark.parametrize("axes", [(3, 0, 1, 2), (1, 2, 0, 3)],
+                         ids=["batch-minor", "batch-middle"])
+def test_staged_batch_keeps_the_requests_memory_order(server, axes):
+    """Requests sliced from a pool laid out another way than C order
+    (``np.asarray`` of a TPU array gives batch-minor) are staged in
+    their own memory order, as a new concatenation would lay them out,
+    and resolve bit-exact."""
+    spy = SpyServer(server)
+    q = serve_cnn.MicroBatchQueue(spy, max_batch=8, timeout_s=1e9)
+    stored = np.ascontiguousarray(_req(server, 12).transpose(axes))
+    pool = stored.transpose(np.argsort(axes))        # (12,) + item_shape
+    reqs = [pool[5:8], pool[0:1], pool[2:6]]
+    tickets = [q.submit(r) for r in reqs]            # 8 rows -> flush
+    assert all(t.ok for t in tickets) and q.staging_allocs == 1
+    (batch,) = spy.batches
+    assert batch.base is q._staging
+    assert batch.strides == np.concatenate(reqs).strides
+    for t, want in zip(tickets, _fresh_logits(server, reqs)):
+        np.testing.assert_array_equal(np.asarray(t.result), want)
+
+
+def test_oversize_and_backpressure_flushes(server):
+    """A group wider than max_batch (the last request overshoots it) is
+    concatenated into a new array; a backpressure flush that fits is
+    staged.  Both resolve bit-exact."""
+    clock = FakeClock()
+    spy = SpyServer(server)
+    q = serve_cnn.MicroBatchQueue(spy, max_batch=8, timeout_s=1e9,
+                                  clock=clock, max_pending=6,
+                                  admission="flush")
+    reqs = [_req(server, n) for n in (3, 2, 3)]
+    tickets = [q.submit(r) for r in reqs]         # 5 + 3 > 6: backpressure
+    assert [t.done for t in tickets] == [True, True, False]
+    assert q.staging_allocs == 1 and spy.batches[-1].base is q._staging
+    q.flush()
+    assert all(t.ok for t in tickets)
+    for t, want in zip(tickets[:2], _fresh_logits(server, reqs[:2])):
+        np.testing.assert_array_equal(np.asarray(t.result), want)
+
+    q = serve_cnn.MicroBatchQueue(spy, max_batch=8, timeout_s=1e9,
+                                  clock=clock)
+    reqs = [_req(server, n) for n in (5, 6)]      # 11 rows > max_batch
+    tickets = [q.submit(r) for r in reqs]
+    assert all(t.ok for t in tickets)
+    assert q.staging_allocs == 0 and spy.batches[-1].shape[0] == 11
+    for t, want in zip(tickets, _fresh_logits(server, reqs)):
+        np.testing.assert_array_equal(np.asarray(t.result), want)
+
+
+def test_single_request_group_passes_without_a_copy(server):
+    spy = SpyServer(server)
+    q = serve_cnn.MicroBatchQueue(spy, max_batch=8, timeout_s=1e9)
+    req = _req(server, 3)
+    t = q.submit(req)
+    q.flush()
+    assert t.ok and spy.batches == [req] and spy.batches[0] is req
+    assert q.staging_allocs == 0
+
+
+def test_fault_drops_the_staging_buffer(server):
+    """A poisoned request in a staged flush: every healthy ticket
+    resolves bit-exact, and the sub-flushes of the recovery write a new
+    buffer, never the one the failed call was given."""
+    from repro.runtime import resilience as rz
+
+    clock = FakeClock()
+    plan = rz.FaultPlan(poison_nan=True)
+    chaos = rz.ChaosServer(server, plan, delay=lambda _dt: None)
+    spy = SpyServer(chaos)
+    q = serve_cnn.MicroBatchQueue(spy, max_batch=8, timeout_s=1e9,
+                                  clock=clock, sleep=clock.advance,
+                                  retry=rz.RetryPolicy(max_retries=0))
+    warm = [_req(server, 4), _req(server, 4)]
+    assert all(t.ok for t in [q.submit(r) for r in warm])
+    assert q.staging_allocs == 1
+    first = q._staging
+    reqs = [_req(server, n) for n in (2, 1, 3, 2)]
+    reqs[1][:] = np.nan
+    tickets = [q.submit(r) for r in reqs]
+    assert all(t.done for t in tickets)
+    assert isinstance(tickets[1].error, rz.RequestPoisoned)
+    healthy = [0, 2, 3]
+    want = _fresh_logits(server, [reqs[i] for i in healthy])
+    for i, w in zip(healthy, want):
+        np.testing.assert_array_equal(np.asarray(tickets[i].result), w)
+    # the root (8 rows) and the [2, 1] half failed: two fresh buffers
+    assert q.staging_allocs == 3
+    staged = [b for b in spy.batches if b.base is not None]
+    assert staged[1].base is first                # the failed root call
+    assert all(b.base is not first for b in staged[2:])
+
+
+# ---------------------------------------------------------------------------
 # Serving contract: no steady-state recompiles, arbitrary stream sizes.
 # ---------------------------------------------------------------------------
 

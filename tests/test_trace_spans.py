@@ -105,6 +105,8 @@ def test_spans_follow_the_served_path(server, tmp_path):
     item_bytes = 4 * int(np.prod(server.item_shape))
     assert [s[3]["bytes"] for s in by["queue.assemble"]] == [
         8 * item_bytes, 2 * item_bytes]
+    # two requests go into the kept buffer; a lone one passes as it came
+    assert [s[3]["staged"] for s in by["queue.assemble"]] == [1, 0]
     assert [s[3]["images"] for s in by["queue.infer"]] == [8, 2]
     assert [s[3]["tickets"] for s in by["queue.resolve"]] == [2, 1]
 
@@ -120,6 +122,26 @@ def test_spans_follow_the_served_path(server, tmp_path):
     (pad,) = by["exe.pad"]
     assert (pad[3]["bucket"], pad[3]["rows"]) == (4, 2)
     assert _inside(pad, infers[1])
+
+
+def test_assemble_span_says_whether_the_group_was_staged(server, tmp_path):
+    """``staged`` is 1 for a multi-request group that fits max_batch and
+    0 for one wider than it, which is concatenated into a new array."""
+    rng = np.random.default_rng(5)
+    q = serve_cnn.MicroBatchQueue(server, max_batch=MAX_BATCH,
+                                  timeout_s=1e9, clock=FakeClock())
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for sizes in ((3, 5), (4, 6)):            # 8 rows, then 10 rows
+            tickets = [q.submit(rng.uniform(0, 1, (n,) + server.item_shape)
+                                .astype(np.float32)) for n in sizes]
+            assert all(t.ok for t in tickets)
+    finally:
+        jax.profiler.stop_trace()
+    assembles = [s for s in trace.read(tmp_path) if s[0] == "queue.assemble"]
+    assert [(s[3]["staged"], s[3]["flush"]) for s in assembles] == [
+        (1, 1), (0, 2)]
+    assert q.staging_allocs == 1
 
 
 def test_no_session_same_logits_and_nothing_recorded(server, tmp_path):
