@@ -7,10 +7,10 @@
 In one process, runs the cell's short window for each program seed (the
 lower reading: what sound runs of the program give) and, for each control
 seed, the same window with the control in the program's place: the plain
-reference computed in bfloat16, one precision step below the float32 that
-the configuration states for its epilogue (the upper reading).  Prints
-each run's compared numbers and a summary line.  The benchmark's own runs
-never run the control.
+reference of the configuration's family computed in bfloat16, one
+precision step below the float32 that the configuration states for its
+epilogue (the upper reading).  Prints each run's compared numbers and a
+summary line.  The benchmark's own runs never run the control.
 """
 
 from __future__ import annotations
@@ -30,14 +30,14 @@ class ControlServer:
     def __init__(self, wl: dict, cfg: dict, weights: dict):
         import jax.numpy as jnp
 
-        import reference
+        import readers
         from repro.runtime import resilience
 
         self.item_shape = tuple(cfg["input_hw"])
         self.exe = types.SimpleNamespace(buckets=tuple(wl["buckets"]))
         self.resilience = resilience.ResilienceStats()
         self.weights = weights
-        self._fwd = reference.make_forward(cfg, dtype=jnp.bfloat16)
+        self._fwd = readers.family(cfg).make_forward(cfg, jnp.bfloat16)
         self.counts = {"compiles": 0, "padded_rows": 0, "executions": 0,
                        "failures": 0}
 

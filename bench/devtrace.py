@@ -9,10 +9,10 @@ This module reads it with ``jax.profiler.ProfileData`` and computes:
 * the window: from the first to the last of the harness's host spans
   (``run.py``: generate, submit, poll, infer, resolve, wait);
 * kernel grouping: device operations whose HLO instruction is named
-  after a kernel's entry point (``KERNELS``: XLA names a Pallas custom
-  call after the jitted wrapper around it, ``radix_conv2d_pallas.<n>``),
-  each attributed to the ``infer`` span that was open while it ran, with
-  that span's image count;
+  after a kernel's entry point (the family's ``KERNELS``: XLA names a
+  Pallas custom call after the jitted wrapper around it,
+  ``radix_conv2d_pallas.<n>``), each attributed to the ``infer`` span
+  that was open while it ran, with that span's image count;
 * the breakdown: the device operations that took most time, by name, and
   the longest idle gaps, each labelled with the innermost host span open
   at its middle.
@@ -27,7 +27,6 @@ import re
 from typing import Dict, List, Optional, Tuple
 
 HOST_SPANS = ("generate", "submit", "poll", "infer", "resolve", "wait")
-KERNELS = {"conv": "radix_conv2d_pallas", "matmul": "radix_matmul_pallas"}
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE = "XLA Ops"
 TOP = 10
@@ -128,13 +127,13 @@ def span_at(host: List[Event], t: int) -> Optional[Event]:
     return best
 
 
-def kernel_calls(devices, host, kind: str, tol_ns: int = CLOCK_TOL_NS):
-    """Per ``infer`` span: (images, [durations in ns of the ``kind``
-    kernel's device events]).  An event belongs to the last span that
-    started before it, allowing for the trace's host-to-device clock
-    error; an event past its span's end by more than that belongs to
-    none."""
-    needle = KERNELS[kind] + "."
+def kernel_calls(devices, host, entry: str, tol_ns: int = CLOCK_TOL_NS):
+    """Per ``infer`` span: (images, [durations in ns of the device events
+    of the kernel whose entry point is ``entry``]).  An event belongs to
+    the last span that started before it, allowing for the trace's
+    host-to-device clock error; an event past its span's end by more
+    than that belongs to none."""
+    needle = entry + "."
     infers = [h for h in host if h.name == "infer"]
     starts = [h.start for h in infers]
     out = []
@@ -150,9 +149,10 @@ def kernel_calls(devices, host, kind: str, tol_ns: int = CLOCK_TOL_NS):
     return out
 
 
-def reduce(path, n_devices: int = 1) -> Optional[dict]:
+def reduce(path, n_devices: int, kernels: Dict[str, str]) -> Optional[dict]:
     """What the metric readers take from one trace (None without a
-    device event or a host span)."""
+    device event or a host span); ``kernels`` is the family's
+    ``KERNELS``."""
     tr = load(path)
     devices = {d: evs for d, evs in tr["devices"].items() if d < n_devices}
     host = tr["host"]
@@ -178,7 +178,8 @@ def reduce(path, n_devices: int = 1) -> Optional[dict]:
     return {
         "window_s": window_ns / 1e9,
         "busy_s": sum(busy_ns) / len(busy_ns) / 1e9,
-        "kernels": {k: kernel_calls(devices, host, k) for k in KERNELS},
+        "kernels": {k: kernel_calls(devices, host, entry)
+                    for k, entry in kernels.items()},
         "breakdown": {
             "device_ops": [[name, t / 1e9] for name, t in top_ops],
             "idle_gaps": labelled,

@@ -18,7 +18,9 @@ warm-in of traffic.  The window then runs for ``--seconds`` (with
 ``--trace 1``, for the cell's ``trace_seconds`` under the profiler).
 After it closes, the device's peak memory is read, the program is freed
 and the logits of a seeded sample of the window's requests are compared
-with the plain reference (``bench/reference.py``).
+with the plain reference of the configuration's model family
+(``bench/families/<family>.py``, named by its ``"family"`` key, which
+also makes the weights, the program's net and the work per kernel).
 
 The run refuses any platform but ``tpu`` and exits non-zero, printing no
 result, when JAX finds no chip, too few chips, or no program next to the
@@ -69,8 +71,16 @@ def workload(name: str) -> dict:
     return wl
 
 
+def config_file(path: pathlib.Path) -> dict:
+    """A configuration, refused (naming ``path``) where its model family
+    cannot be found."""
+    cfg = load_json(path)
+    readers.family(cfg, str(path))
+    return cfg
+
+
 def config(name: str) -> dict:
-    return load_json(BENCH / "configs" / f"{name}.json")
+    return config_file(BENCH / "configs" / f"{name}.json")
 
 
 def cell_metrics(cell: str, trace: bool) -> List[dict]:
@@ -356,11 +366,9 @@ def compare(cfg: dict, weights: dict, pool, sample, chunk: int, dtype=None):
     delivered: (mismatched logits, compared logits)."""
     import jax.numpy as jnp
 
-    import reference
-
     if not sample:
         return 0, 0
-    fwd = reference.make_forward(cfg, dtype=dtype or jnp.float32)
+    fwd = readers.family(cfg).make_forward(cfg, dtype or jnp.float32)
     rows = np.concatenate([np.arange(r.pool_offset, r.pool_offset + r.images)
                            for r in sample])
     got = np.concatenate([r.logits for r in sample])
@@ -395,12 +403,13 @@ def build(wl: dict, cfg: dict, seed: int, serve_cnn,
 
     import netgen
 
+    family = readers.family(cfg)
     clock = time.perf_counter
     key = netgen.root_key(seed)
     t = clock()
-    weights = netgen.make_weights(cfg, jax.random.fold_in(key, 0))
+    weights = family.make_weights(cfg, jax.random.fold_in(key, 0))
     jax.block_until_ready(weights)
-    qnet = netgen.quantized_net(cfg, weights)
+    qnet = family.program_net(cfg, weights)
     item = tuple(cfg["input_hw"])
     server = make_server(serve_cnn, qnet, wl, cfg, weights)
     t_weights = clock() - t
@@ -610,7 +619,8 @@ def measure(wl: dict, cfg: dict, seed: int, seconds: float, trace: bool,
 
     if trace:
         import devtrace
-        run.trace = devtrace.reduce(tmp, n_devices=chips)
+        run.trace = devtrace.reduce(tmp, chips,
+                                    readers.family(cfg).KERNELS)
         shutil.rmtree(tmp, ignore_errors=True)
 
     t = clock()

@@ -33,21 +33,22 @@ PER_LAYER = [{"name": n, "unit": u} for n, u in (
 
 @pytest.fixture(scope="session")
 def tiny():
-    """Run the harness's measurement on the CPU at the test-only tiny
-    configuration: ``tiny(cell, trace=False, **measure_kwargs)``.  The
-    look for a chip is skipped; everything after it is the real run."""
+    """Run the harness's measurement on the CPU at a test-only tiny
+    configuration, the one the cell's file under ``data`` names:
+    ``tiny(cell, trace=False, **measure_kwargs)``.  The look for a chip
+    is skipped; everything after it is the real run."""
     import jax
 
     import run
 
     serve_cnn = run.import_program()
-    cfg = json.loads((DATA / "tiny_cnn.json").read_text())
     peak = json.loads((BENCH / "peaks.json").read_text())[
         "devices"]["TPU v5 lite"]
 
     def go(cell, trace=False, seed=2**31 + 11, seconds=0.5, **kw):
         wl = json.loads((DATA / f"{cell}.json").read_text())
         wl["name"] = cell
+        cfg = run.config_file(DATA / f"{wl['config']}.json")
         kw.setdefault("metrics", PER_LAYER if trace else END_TO_END)
         chips = int(wl["chips"])
         return run.measure(wl, cfg, seed, seconds, trace,

@@ -18,14 +18,15 @@ def test_reference_agrees_with_the_program_oracle():
     import jax.numpy as jnp
 
     import netgen
-    import reference
+    import readers
     from conftest import DATA
     from repro import api
 
     cfg = json.loads((DATA / "tiny_cnn.json").read_text())
-    w = netgen.make_weights(cfg, netgen.root_key(3))
+    family = readers.family(cfg)
+    w = family.make_weights(cfg, netgen.root_key(3))
     x = netgen.images(cfg, jax.random.PRNGKey(4), 64)
-    want = api.oracle(netgen.quantized_net(cfg, w), x, mode="packed")
-    assert bool(jnp.array_equal(reference.make_forward(cfg)(w, x), want))
-    low = reference.make_forward(cfg, dtype=jnp.bfloat16)(w, x)
+    want = api.oracle(family.program_net(cfg, w), x, mode="packed")
+    assert bool(jnp.array_equal(family.make_forward(cfg)(w, x), want))
+    low = family.make_forward(cfg, dtype=jnp.bfloat16)(w, x)
     assert int((low != want).sum()) > 0

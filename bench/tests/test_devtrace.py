@@ -7,8 +7,12 @@ import pathlib
 import pytest
 
 import devtrace
+import readers
+import run
 
 TRACE = pathlib.Path(devtrace.__file__).resolve().parent / "testdata"
+# the recorded trace is of the LeNet-5 configuration
+KERNELS = readers.family(run.config("lenet5_r4_bitserial")).KERNELS
 
 
 def test_merge_unions_overlaps():
@@ -46,11 +50,11 @@ def test_kernel_calls_attributes_events_to_infer_spans():
                devtrace.Event("radix_conv2d_pallas.2", 22, 30),
                devtrace.Event("radix_conv2d_pallas.1", 60, 70)]}
     host = [devtrace.Event("infer", 10, 40, images=8)]
-    assert devtrace.kernel_calls(dev, host, "conv", tol_ns=5) == [
-        (8, [8, 8])]
-    assert devtrace.kernel_calls(dev, host, "matmul", tol_ns=5) == []
+    conv, matmul = KERNELS["conv"], KERNELS["matmul"]
+    assert devtrace.kernel_calls(dev, host, conv, tol_ns=5) == [(8, [8, 8])]
+    assert devtrace.kernel_calls(dev, host, matmul, tol_ns=5) == []
     # within the clock tolerance, the late event joins the call too
-    assert devtrace.kernel_calls(dev, host, "conv", tol_ns=30) == [
+    assert devtrace.kernel_calls(dev, host, conv, tol_ns=30) == [
         (8, [8, 8, 10])]
 
 
@@ -71,7 +75,7 @@ def test_chip_trace_has_device_ops_and_host_spans(chip_trace):
 
 
 def test_chip_trace_reduces(chip_trace):
-    red = devtrace.reduce(chip_trace)
+    red = devtrace.reduce(chip_trace, 1, KERNELS)
     assert 0 < red["busy_s"] < red["window_s"]
     ops = red["breakdown"]["device_ops"]
     gaps = red["breakdown"]["idle_gaps"]

@@ -10,9 +10,13 @@ import numpy as np
 import pytest
 
 import devtrace
+import readers
+import run
 import spantrace
 
 TRACE = pathlib.Path(devtrace.__file__).resolve().parent / "testdata"
+# the recorded trace is of the LeNet-5 configuration
+KERNELS = readers.family(run.config("lenet5_r4_bitserial")).KERNELS
 Ev = devtrace.Event
 
 
@@ -100,17 +104,16 @@ def test_cpu_trace_holds_the_program_spans(tmp_path):
     the loader finds the program's spans with their arguments."""
     import jax
 
-    import run
-
     serve_cnn = run.import_program()
     from repro.runtime import trace
 
-    cfg = json.loads((pathlib.Path(__file__).parent / "data"
-                      / "tiny_cnn.json").read_text())
+    cfg = run.config_file(pathlib.Path(__file__).parent / "data"
+                          / "tiny_cnn.json")
     import netgen
+    family = readers.family(cfg)
     key = netgen.root_key(5)
-    weights = netgen.make_weights(cfg, jax.random.fold_in(key, 0))
-    qnet = netgen.quantized_net(cfg, weights)
+    weights = family.make_weights(cfg, jax.random.fold_in(key, 0))
+    qnet = family.program_net(cfg, weights)
     item = tuple(cfg["input_hw"])
     server = serve_cnn.CNNServer(qnet, item, buckets=(4,), data_parallel=1)
     server.warmup()
@@ -145,7 +148,7 @@ def _digest(calls) -> str:
 def test_devtrace_readings_of_the_chip_trace_are_pinned(chip_trace):
     """The window, busy time and kernel join of the recorded trace, as
     the reduction read them when the benchmark was accepted."""
-    red = devtrace.reduce(chip_trace)
+    red = devtrace.reduce(chip_trace, 1, KERNELS)
     assert red["window_s"] == 0.249215736
     assert red["busy_s"] == 0.008075136
     conv, matmul = red["kernels"]["conv"], red["kernels"]["matmul"]
@@ -162,7 +165,7 @@ def test_chip_trace_of_a_program_without_spans(chip_trace):
     idle time covers exactly the window's idle time, its gaps read as
     devtrace's, and no module is a plan call (``jit_forward``)."""
     red = spantrace.reduce(chip_trace)
-    dev = devtrace.reduce(chip_trace)
+    dev = devtrace.reduce(chip_trace, 1, KERNELS)
     assert red["window_s"] == dev["window_s"]
     assert sum(red["idle_by_span"].values()) == pytest.approx(
         dev["window_s"] - dev["busy_s"], rel=1e-9)

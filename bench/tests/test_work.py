@@ -16,12 +16,14 @@ def cfg(name):
 
 def test_vgg11_counts():
     c = cfg("vgg11_r4_fused")
-    conv = sum(l.macs for l in work.layers(c) if l.kind == "conv")
-    linear = sum(l.macs for l in work.layers(c) if l.kind == "linear")
+    conv = sum(l.macs for l in work.layers(c) if l.kernel == "conv")
+    linear = sum(l.macs for l in work.layers(c) if l.kernel == "matmul")
     assert conv == 7_485_456_384
     assert linear == 119_947_264
     assert work.ops_per_image(c) == pytest.approx(15.21e9, rel=1e-3)
+    assert work.ops_per_image(c) == 15_210_807_296
     assert work.ops_per_image(c, "conv") == 2 * conv
+    assert work.ops_per_image(c, "matmul") == 2 * linear
 
 
 def test_lenet5_counts():
@@ -46,7 +48,7 @@ def test_bytes_are_unpadded_levels_weights_and_output():
 def test_least_time_takes_the_binding_roof():
     peak = work.peaks("TPU v5 lite")
     c = cfg("vgg11_r4_fused")
-    fc1 = [l for l in work.layers(c) if l.kind == "linear"][0]
+    fc1 = [l for l in work.layers(c) if l.kernel == "matmul"][0]
     # one image: the 102.8 MB of weights bind, not the 0.2 GOP
     assert fc1.least_time_s(1, peak) == pytest.approx(
         fc1.bytes(1) / 819e9)
@@ -66,3 +68,16 @@ def test_peaks_table_is_keyed_by_device_kind():
 def test_unknown_device_kind_raises():
     with pytest.raises(KeyError, match="no peaks"):
         work.peaks("cpu")
+
+
+def test_roofline_share_of_a_kernel_the_family_lacks_is_left_out():
+    import types
+
+    c = json.loads((pathlib.Path(work.__file__).resolve().parent / "tests"
+                    / "data" / "tiny_cnn.json").read_text())
+    fc = [l for l in work.layers(c) if l.kernel == "matmul"]
+    run = types.SimpleNamespace(
+        cfg=c, chips=1, peak=work.peaks("TPU v5 lite"),
+        trace={"kernels": {"matmul": [(4, [1000] * len(fc))]}})
+    assert work.roofline_share(run, "matmul") > 0
+    assert work.roofline_share(run, "conv") is None

@@ -1,5 +1,7 @@
 """Logical work of a configuration, and the chip's peaks.
 
+Each family (``bench/families/<family>.py``) lists its configuration's
+work per kernel call as :class:`LayerWork` entries (``layers(cfg)``).
 Work counts what the network needs, whatever implements it, so that no
 change to padding, tiling or dataflow alone can raise a roofline share:
 
@@ -18,11 +20,10 @@ from __future__ import annotations
 
 import dataclasses
 import json
-import math
 import pathlib
 from typing import List
 
-import netgen
+import readers
 
 PEAKS_FILE = pathlib.Path(__file__).resolve().parent / "peaks.json"
 
@@ -30,7 +31,7 @@ PEAKS_FILE = pathlib.Path(__file__).resolve().parent / "peaks.json"
 @dataclasses.dataclass(frozen=True)
 class LayerWork:
     name: str
-    kind: str             # "conv" or "linear"
+    kernel: str           # a key of the family's KERNELS
     macs: int             # per image
     in_bytes: int         # per image
     out_bytes: int        # per image
@@ -50,32 +51,15 @@ class LayerWork:
 
 
 def layers(cfg: dict) -> List[LayerWork]:
-    """Per conv / linear layer of ``cfg``, in order."""
-    wshapes, outs = netgen.layer_shapes(cfg)
-    shape = tuple(cfg["input_hw"])
-    n_affine = sum(s is not None for s in wshapes)
-    out, seen = [], 0
-    for layer, wshape, oshape in zip(cfg["layers"], wshapes, outs):
-        if wshape is not None:
-            seen += 1
-            positions = math.prod(oshape[:-1])
-            fan_in = math.prod(wshape[:-1])
-            out_elem = 4 if seen == n_affine else 1
-            out.append(LayerWork(
-                name=f"{layer['kind']}{seen}",
-                kind=layer["kind"],
-                macs=positions * fan_in * wshape[-1],
-                in_bytes=math.prod(shape),
-                out_bytes=math.prod(oshape) * out_elem,
-                weight_bytes=math.prod(wshape)))
-        shape = oshape
-    return out
+    """The work of ``cfg`` per kernel call, in the order the plan calls
+    its kernels, as its family lists it."""
+    return readers.family(cfg).layers(cfg)
 
 
-def ops_per_image(cfg: dict, kind: str = None) -> float:
-    """Logical operations per image, over all layers or one kind."""
+def ops_per_image(cfg: dict, kernel: str = None) -> float:
+    """Logical operations per image, over all layers or one kernel's."""
     return sum(2.0 * l.macs for l in layers(cfg)
-               if kind is None or l.kind == kind)
+               if kernel is None or l.kernel == kernel)
 
 
 def peaks(device_kind: str) -> dict:
@@ -88,20 +72,18 @@ def peaks(device_kind: str) -> dict:
     return table[device_kind]
 
 
-KERNEL_LAYERS = {"conv": "conv", "matmul": "linear"}
-
-
 def roofline_share(run, kernel: str):
-    """Percent: the least time of the logical work of the ``kernel``
-    layers of every server call in the trace (``run.trace["kernels"]``),
-    over those kernels' device time.  Calls whose event count differs from
-    the layer count are left out; None where no call is left."""
+    """Percent: the least time of the logical work of the layers that run
+    on ``kernel`` in every server call in the trace
+    (``run.trace["kernels"]``), over those kernels' device time.  Calls
+    whose event count differs from the layer count are left out; None
+    where no call is left, or the family has no such kernel."""
     tr = run.trace
     if not tr:
         return None
-    lays = [l for l in layers(run.cfg) if l.kind == KERNEL_LAYERS[kernel]]
+    lays = [l for l in layers(run.cfg) if l.kernel == kernel]
     least = device = 0.0
-    for images, durs in tr["kernels"][kernel]:
+    for images, durs in tr["kernels"].get(kernel, []):
         if len(durs) != len(lays):
             continue
         per_chip = images / run.chips
