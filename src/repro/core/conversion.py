@@ -15,7 +15,12 @@ A network is ``(static, params)``:
 
 * ``static``: tuple of ``(kind, cfg)`` pairs; ``kind`` in
   {"conv", "linear", "pool", "flatten"}; cfg is a dict of ints/strings
-  (stride, padding, window, mode).
+  (stride, padding, window, mode).  A conv's ``padding`` is "SAME",
+  "VALID" or explicit ``((lo, hi), (lo, hi))``; a pool takes an optional
+  ``stride`` (default: the window) and ``padding``.  A converted net may
+  also hold ``"block"`` entries, basic residual blocks with ``{"stride":
+  s, "proj": bool}`` (DESIGN.md §2); :func:`convert` does not produce
+  them (a float residual net's conversion is not implemented).
 * ``params``: list with one entry per layer; {"w": ..., "b": ...} for
   conv/linear, ``None`` for pool/flatten.
 
@@ -165,6 +170,11 @@ class QuantizedNet:
 
     qlayers mirrors ``static``; each entry is a dict:
       conv/linear: {"w_q", "b_int", "mult"(None for logits layer)}
+      block: {"a": {"w_q", "b_int", "mult"}, "b": {"w_q", "b_int",
+              "mult"}, "proj": {"w_q", "b_int"} or None,
+              "res_scale": int32 (C_out,)} — 3x3/s conv_a, 3x3/1 conv_b,
+              the optional 1x1/s projection and the join's integer
+              factor (DESIGN.md §2)
       pool/flatten: None
     ``logit_scale`` maps the last integer accumulator to float logits.
 

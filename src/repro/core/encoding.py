@@ -292,6 +292,9 @@ class EncodingSpec:
     backends: ClassVar[Tuple[str, ...]] = ()
     kernel_dataflows: ClassVar[Tuple[str, ...]] = ()
     pool_modes: ClassVar[Tuple[str, ...]] = ()
+    # residual blocks (layer kind "block", DESIGN.md §2): tested, and so
+    # accepted, for the radix code only
+    residual_blocks: ClassVar[bool] = False
     levels_doc: ClassVar[str] = "?"    # human formula for docs/encodings.md
     periods: ClassVar[int] = 1         # repeated-period count (phase: P)
 
@@ -446,9 +449,14 @@ class EncodingSpec:
 
         Raises:
             ValueError: a pool layer uses a mode this encoding does not
-                preserve, naming the supported modes.
+                preserve, naming the supported modes; or a residual
+                ``"block"`` in a net whose encoding does not run them.
         """
         for kind, cfg in static:
+            if kind == "block" and not self.residual_blocks:
+                raise ValueError(
+                    f"{self.name} encoding does not run layer kind "
+                    f"'block' (residual blocks run in the radix code)")
             if kind == "pool" and not self.supports_pool(
                     cfg.get("mode", "or")):
                 raise ValueError(
@@ -536,6 +544,7 @@ class RadixEncoding(EncodingSpec):
     backends: ClassVar[Tuple[str, ...]] = ("kernels", "jnp")
     kernel_dataflows: ClassVar[Tuple[str, ...]] = ("fused", "bitserial")
     pool_modes: ClassVar[Tuple[str, ...]] = ("or", "avg", "max")
+    residual_blocks: ClassVar[bool] = True
     levels_doc: ClassVar[str] = "2^T"
 
     @property

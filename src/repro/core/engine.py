@@ -122,6 +122,8 @@ def _forward(
             state = _requant_or_logits(acc, qp, qnet, spec, snn)
         elif kind == "pool":
             state = _pool(state, cfg, spec, snn)
+        elif kind == "block":
+            state = _block(state, cfg, qp, spec, snn)
         elif kind == "flatten":
             if snn:
                 state = state.reshape(state.shape[0], state.shape[1], -1)
@@ -174,37 +176,93 @@ def _requant_or_logits(acc, qp, qnet, spec, snn):
 
 def _pool(state, cfg, spec, snn):
     w, pool_mode = cfg["window"], cfg.get("mode", "or")
+    stride, padding = cfg.get("stride", w), cfg.get("padding", "VALID")
+    geo = dict(stride=stride, padding=padding)
     if not spec.supports_pool(pool_mode):
         raise ValueError(
             f"{spec.name} encoding does not preserve pool mode "
             f"{pool_mode!r} (supported: {spec.pool_modes})")
     if snn:
         if pool_mode == "or":
-            return layers.snn_or_pool(state, w)
+            return layers.snn_or_pool(state, w, **geo)
         if pool_mode == "avg":
             # per-plane sum pool; planes become multi-bit but stay linear —
             # hardware note: avg mode needs an output requantizer (DESIGN §2)
-            return jax.vmap(lambda p: layers.q_avg_pool(p, w))(state)
+            return jax.vmap(lambda p: layers.q_avg_pool(p, w, **geo))(state)
         if pool_mode == "max":
-            if spec.radix_planes:
+            if spec.radix_planes and stride == w and padding == "VALID":
                 # bit-plane-domain lexicographic max (the paper's pooling
                 # unit never decodes) — valid whenever planes are the
-                # binary expansion of the packed level
+                # binary expansion of the packed level and windows tile
                 packed = layers.snn_max_pool(state, w)
             else:
-                # period-repeated codes (phase, P > 1): decode, pool the
-                # packed levels, re-encode
+                # period-repeated codes (phase, P > 1) or overlapping /
+                # padded windows: decode, pool the packed levels, re-encode
                 packed = layers.q_max_pool(
-                    spec.decode(state).astype(spec.packed_dtype), w)
+                    spec.decode(state).astype(spec.packed_dtype), w, **geo)
             return spec.encode(packed)
         raise ValueError(pool_mode)
     if pool_mode == "or":
-        return layers.q_or_pool(state, w)
+        return layers.q_or_pool(state, w, **geo)
     if pool_mode == "avg":
-        return layers.q_avg_pool(state, w)
+        return layers.q_avg_pool(state, w, **geo)
     if pool_mode == "max":
-        return layers.q_max_pool(state, w)
+        return layers.q_max_pool(state, w, **geo)
     raise ValueError(pool_mode)
+
+
+BLOCK_PAD = ((1, 1), (1, 1))   # a basic block's 3x3 convs pad one each side
+
+
+def _block(state, cfg, qp, spec, snn):
+    """One basic residual block on the jnp paths (DESIGN.md §2):
+    ``a = requant(conv3x3_s(x) + b_a)``, the skip ``x`` (levels) or a
+    1x1/s projection's int32 accumulator, then
+    ``out = requant(conv3x3_1(a) + b_b + res_scale * skip)``."""
+    s = cfg.get("stride", 1)
+
+    def acc(x, q, stride, padding):
+        if snn:
+            per = jax.vmap(lambda p: layers._int_conv(
+                p, q["w_q"], stride, padding))(x)
+            return spec.reduce_planes(per) + q["b_int"]
+        return layers.q_conv2d(x, q["w_q"], q["b_int"], stride=stride,
+                               padding=padding)
+
+    a = spec.requantize(acc(state, qp["a"], s, BLOCK_PAD), qp["a"]["mult"])
+    if snn:
+        a = spec.encode(a)
+    if qp["proj"] is not None:
+        skip = acc(state, qp["proj"], s, "VALID")
+    else:
+        skip = spec.decode(state) if snn else state.astype(jnp.int32)
+    join = acc(a, qp["b"], 1, BLOCK_PAD) + jnp.asarray(
+        qp["res_scale"], jnp.int32) * skip
+    out = spec.requantize(join, qp["b"]["mult"])
+    return spec.encode(out) if snn else out
+
+
+def conv_pads(padding, h: int, w: int, kh: int, kw: int,
+              stride: int) -> Tuple[Tuple[int, int], Tuple[int, int]]:
+    """Explicit ``((lo, hi), (lo, hi))`` zero pads of H and W for a conv
+    or pool ``padding``: "SAME" (XLA's), "VALID", or explicit pairs.
+    Anything else raises, so no padding is silently dropped."""
+    from repro.kernels import ops as kops
+
+    if padding == "SAME":
+        return kops.same_pads(h, kh, stride), kops.same_pads(w, kw, stride)
+    if padding == "VALID":
+        return (0, 0), (0, 0)
+    try:
+        (a, b), (c, d) = padding
+        pads = ((int(a), int(b)), (int(c), int(d)))
+    except (TypeError, ValueError):
+        pads = None
+    if isinstance(padding, str) or pads is None or min(sum(pads, ())) < 0:
+        raise ValueError(
+            f"padding must be 'SAME', 'VALID' or ((lo, hi), (lo, hi)), "
+            f"got {padding!r}")
+    return pads
 
 
 # ---------------------------------------------------------------------------
@@ -221,6 +279,7 @@ class PlanLayerInfo:
     out_dtype: str                 # what the plan actually writes
     act_write_bytes: int           # this plan (fused epilogue, packed uint8)
     act_write_bytes_int32: int     # unfused baseline (raw int32 accumulator)
+    skip_read_bytes: int = 0       # residual joins: skip operand bytes read
 
 
 @dataclasses.dataclass
@@ -243,8 +302,10 @@ class CompiledPlan:
 
     The jitted program is named ``plan_b<bucket>`` and each layer runs
     under a ``jax.named_scope`` (``input``, ``conv1``…, ``pool1``…,
-    ``flatten``, ``fc1``…, ``output``): :meth:`layer_map` reads which
-    instruction of the compiled program belongs to which layer.
+    ``block1``…, ``flatten``, ``fc1``…, ``output``): :meth:`layer_map`
+    reads which instruction of the compiled program belongs to which
+    layer; a residual block's pads, occupancy prepasses, kernels and join
+    all sit under its ``block<i>``.
     """
 
     input_shape: Tuple[int, ...]
@@ -303,7 +364,7 @@ class CompiledPlan:
 
     def layer_map(self) -> Dict[str, str]:
         """HLO instruction name -> layer scope (``input``, ``conv1``,
-        ``pool1``, ``flatten``, ``fc1``, ``output``…) for every
+        ``pool1``, ``block1``, ``flatten``, ``fc1``, ``output``…) for every
         instruction of this plan's compiled program whose metadata names
         one: the names a device trace gives the plan's operations.
         Compiles the plan ahead of time (a cache hit where the persistent
@@ -321,16 +382,21 @@ class CompiledPlan:
             "fused_write_bytes": fused,
             "int32_write_bytes": unfused,
             "traffic_ratio": unfused / max(fused, 1),
+            # what the residual joins read in their conv epilogues: uint8
+            # identities and int32 projections, logical shapes
+            "skip_read_bytes": sum(l.skip_read_bytes for l in self.layers),
         }
 
 
 # per-call skip counts kept on the device before the oldest are summed
 SKIP_FOLD = 64
 # a plan's layer scopes, as named_scope puts them in op_name metadata
-_LAYER_SCOPE = re.compile(r"^(?:input|output|flatten|(?:conv|pool|fc)\d+)$")
+_LAYER_SCOPE = re.compile(
+    r"^(?:input|output|flatten|(?:conv|pool|fc|block)\d+)$")
 _HLO_OP = re.compile(r'^\s*(?:ROOT )?%?([\w.\-]+) = .*\bop_name="([^"]*)"')
 # scope prefix per layer kind, numbered from 1 in network order
-_SCOPE_PREFIX = {"conv": "conv", "pool": "pool", "linear": "fc"}
+_SCOPE_PREFIX = {"conv": "conv", "pool": "pool", "linear": "fc",
+                 "block": "block"}
 
 
 def layer_scopes(hlo_text: str) -> Dict[str, str]:
@@ -443,7 +509,10 @@ def _compile_plan_impl(
     from repro.kernels import autotune as autotune_mod   # deferred:
     from repro.kernels import ops as kops                # optional path
     from repro.kernels.autotune import KernelConfig
-    from repro.kernels.radix_conv import radix_conv2d_pallas
+    from repro.kernels.radix_conv import (
+        radix_conv2d_pallas,
+        radix_conv2d_res_pallas,
+    )
     from repro.kernels.radix_matmul import radix_matmul_pallas
 
     # The spec's declared KernelSchedule is everything the kernels need:
@@ -517,6 +586,109 @@ def _compile_plan_impl(
         row, occ_bits = kops.plane_occupancy(state, in_bits)
         return row, (in_bits - occ_bits.sum()) * periods
 
+    def _fit(a, c):
+        """``a`` with ``c`` channels: padding lanes (level 0) added or
+        dropped, where two strategies pad one width differently."""
+        have = a.shape[-1]
+        if have < c:
+            return jnp.pad(a, ((0, 0),) * (a.ndim - 1) + ((0, c - have),))
+        return a[..., :c] if have > c else a
+
+    def conv_step(name, w_q, b_int, mult, *, h, w, c_pad, in_bits, stride,
+                  padding, res_scale=None, skip_dtype=None):
+        """One conv of the plan, its strategy resolved as every conv's is
+        (``tuned_tiles`` records it under ``name``): ``(h_out, w_out,
+        out_channels, params, apply)``.
+
+        ``apply(state, p)`` pads, runs the occupancy prepass and the
+        kernel: without ``mult`` the int32 accumulator (plus ``b_int``
+        when given), with it the fused epilogue's levels.  ``res_scale``
+        makes it a residual join (DESIGN.md §2): ``apply(state, p,
+        skip)`` adds ``res_scale * skip`` (a ``skip_dtype`` operand of
+        the output's shape) in the epilogue.  The XLA twin keeps
+        out-channels unpadded (the backend compiler needs no alignment —
+        downstream layers fold whatever physical channel count they're
+        handed); the Pallas path pads to the config's bco multiple."""
+        kh, kw, cin, cout = w_q.shape
+        (pa, pb), (pc, pd) = conv_pads(padding, h, w, kh, kw, stride)
+        pads = (((0, 0), (pa, pb), (pc, pd), (0, 0))
+                if pa or pb or pc or pd else None)
+        hp, wp = h + pa + pb, w + pc + pd
+        h_out, w_out = (hp - kh) // stride + 1, (wp - kw) // stride + 1
+        w_cin = jnp.pad(w_q, ((0, 0), (0, 0), (0, c_pad - cin), (0, 0)))
+        raw, joined = mult is None, res_scale is not None
+
+        def build(kcfg):
+            xla = kcfg.impl == "xla"
+            cop, bco = ((cout, None) if xla
+                        else kops._block(cout, pref=kcfg.bco))
+            p = {"w": jnp.pad(w_cin, ((0, 0), (0, 0), (0, 0),
+                                      (0, cop - cout)))}
+            if raw and b_int is not None:
+                p["b"] = jnp.asarray(b_int, jnp.int32)
+            elif not raw:
+                p["bias"], p["mult"] = kops.epilogue_rows(
+                    b_int, mult, cout, cop, encoding=spec)
+            if joined:
+                p["r"] = jnp.pad(jnp.asarray(res_scale, jnp.int32),
+                                 (0, cop - cout)).reshape(1, cop)
+            kern = dict(num_steps=in_bits, method=method, stride=stride,
+                        periods=periods, mxu_dtype=kcfg.mxu_dtype)
+            if not xla:
+                kern.update(bco=bco, interpret=interp, plane_parallel=(
+                    kcfg.plane_parallel and method == "bitserial"))
+            epi = {} if raw else dict(out_level=sched.out_level,
+                                      out_grid=out_grid)
+            if not (raw or xla):
+                epi["out_steps"] = T
+
+            def apply(state, p, skip=None):
+                if pads is not None:
+                    state = jnp.pad(state, pads)
+                occ, skipped = _occ(state, in_bits)
+                if raw:
+                    acc = (kops._xla_conv2d(state, p["w"], None, None, occ,
+                                            **kern) if xla else
+                           radix_conv2d_pallas(state, p["w"], occupancy=occ,
+                                               **kern)[..., :cout])
+                    return (acc + p["b"] if "b" in p else acc), skipped
+                res = () if skip is None else (_fit(skip, cop), p["r"])
+                if xla:
+                    return kops._xla_conv2d(
+                        state, p["w"], p["bias"], p["mult"], occ, *res,
+                        **kern, **epi), skipped
+                fn = radix_conv2d_res_pallas if joined else \
+                    radix_conv2d_pallas
+                return fn(state, p["w"], *res, occupancy=occ,
+                          bias=p["bias"], mult=p["mult"], **kern,
+                          **epi), skipped
+            return cop, p, apply
+
+        layer_sched = encoding.KernelSchedule(
+            packed_bits=in_bits, periods=periods, out_grid=out_grid)
+        sample = skip_sample = None
+        if autotune:
+            sample = _tune_sample((batch, h, w, c_pad), in_bits)
+            if joined:
+                skip_sample = _tune_sample(
+                    (batch, h_out, w_out, cout),
+                    T if np.dtype(skip_dtype) == np.uint8 else 16)
+
+        def thunk(c):
+            _, p_c, a_c = build(c)
+            return lambda: a_c(sample, p_c, skip_sample)[0]
+
+        kcfg = _resolve_cfg(
+            name,
+            lambda: autotune_mod.conv_key(
+                hp, wp, c_pad, kh, kw, cout, stride, layer_sched, method,
+                batch=batch, epilogue=not raw, sparsity=True, joined=joined),
+            lambda: autotune_mod.conv_candidates(
+                hp, wp, c_pad, kh, kw, cout, layer_sched, method,
+                interpret=interp, act_dtypes=("u8",)),
+            thunk)
+        return (h_out, w_out) + build(kcfg)
+
     for (kind, cfg), qp in zip(qnet.static, qnet.qlayers):
         if kind in _SCOPE_PREFIX:
             scope_counts[kind] = scope_counts.get(kind, 0) + 1
@@ -527,119 +699,13 @@ def _compile_plan_impl(
             kh, kw, cin, cout = qp["w_q"].shape
             assert cin == c_real, (cin, c_real)
             stride = cfg.get("stride", 1)
-            pads = None
-            if cfg.get("padding", "VALID") == "SAME":
-                pads = ((0, 0), kops.same_pads(h, kh, stride),
-                        kops.same_pads(w, kw, stride), (0, 0))
-            hp = h + (pads[1][0] + pads[1][1] if pads else 0)
-            wp = w + (pads[2][0] + pads[2][1] if pads else 0)
-            in_shape_phys = (batch, h, w, c_pad)   # this layer's input
-            in_bits = bits
-            h = (hp - kh) // stride + 1
-            w = (wp - kw) // stride + 1
-            w_cin = jnp.pad(qp["w_q"],
-                            ((0, 0), (0, 0), (0, c_pad - cin), (0, 0)))
             last = qp["mult"] is None
             name = f"conv{kh}x{kw}x{cin}->{cout}" + (f"/s{stride}"
                                                      if stride > 1 else "")
-
-            def build_conv(kcfg, *, pads=pads, stride=stride, in_bits=in_bits,
-                           cout=cout, w_cin=w_cin, qp=qp, last=last):
-                """(out_channels, params, apply) for one conv strategy.
-
-                The XLA twin keeps out-channels unpadded (the backend
-                compiler needs no alignment — downstream layers fold
-                whatever physical channel count they're handed); the
-                Pallas path pads to the config's bco multiple."""
-                if kcfg.impl == "xla":
-                    if last:
-                        p = {"w": w_cin,
-                             "b": jnp.asarray(qp["b_int"], jnp.int32)}
-
-                        def apply(state, p, *, kcfg=kcfg):
-                            if pads is not None:
-                                state = jnp.pad(state, pads)
-                            occ, skipped = _occ(state, in_bits)
-                            acc = kops._xla_conv2d(
-                                state, p["w"], None, None, occ,
-                                num_steps=in_bits, method=method,
-                                stride=stride, periods=periods,
-                                mxu_dtype=kcfg.mxu_dtype)
-                            return acc + p["b"], skipped
-                        return cout, p, apply
-                    bias_row, mult_row = kops.epilogue_rows(
-                        qp["b_int"], qp["mult"], cout, cout, encoding=spec)
-                    p = {"w": w_cin, "bias": bias_row, "mult": mult_row}
-
-                    def apply(state, p, *, kcfg=kcfg):
-                        if pads is not None:
-                            state = jnp.pad(state, pads)
-                        occ, skipped = _occ(state, in_bits)
-                        return kops._xla_conv2d(
-                            state, p["w"], p["bias"], p["mult"], occ,
-                            num_steps=in_bits, method=method, stride=stride,
-                            periods=periods, mxu_dtype=kcfg.mxu_dtype,
-                            out_level=sched.out_level,
-                            out_grid=out_grid), skipped
-                    return cout, p, apply
-
-                cop, bco = kops._block(cout, pref=kcfg.bco)
-                w_p = jnp.pad(w_cin, ((0, 0), (0, 0), (0, 0),
-                                      (0, cop - cout)))
-                pp = kcfg.plane_parallel and method == "bitserial"
-                if last:
-                    p = {"w": w_p, "b": jnp.asarray(qp["b_int"], jnp.int32)}
-
-                    def apply(state, p, *, bco=bco, kcfg=kcfg, pp=pp):
-                        if pads is not None:
-                            state = jnp.pad(state, pads)
-                        occ, skipped = _occ(state, in_bits)
-                        acc = radix_conv2d_pallas(
-                            state, p["w"], num_steps=in_bits, method=method,
-                            bco=bco, stride=stride, interpret=interp,
-                            periods=periods, occupancy=occ,
-                            mxu_dtype=kcfg.mxu_dtype, plane_parallel=pp,
-                        )[..., :cout]
-                        return acc + p["b"], skipped
-                    return cop, p, apply
-                bias_row, mult_row = kops.epilogue_rows(
-                    qp["b_int"], qp["mult"], cout, cop, encoding=spec)
-                p = {"w": w_p, "bias": bias_row, "mult": mult_row}
-
-                def apply(state, p, *, bco=bco, kcfg=kcfg, pp=pp):
-                    if pads is not None:
-                        state = jnp.pad(state, pads)
-                    occ, skipped = _occ(state, in_bits)
-                    return radix_conv2d_pallas(
-                        state, p["w"], num_steps=in_bits, method=method,
-                        bco=bco, stride=stride, interpret=interp,
-                        periods=periods, occupancy=occ,
-                        bias=p["bias"], mult=p["mult"], out_steps=T,
-                        out_level=sched.out_level, out_grid=out_grid,
-                        mxu_dtype=kcfg.mxu_dtype, plane_parallel=pp,
-                    ), skipped
-                return cop, p, apply
-
-            layer_sched = encoding.KernelSchedule(
-                packed_bits=in_bits, periods=periods, out_grid=out_grid)
-            sample = _tune_sample(in_shape_phys, in_bits) if autotune \
-                else None
-
-            def _build_thunk(c, *, build_conv=build_conv, sample=sample):
-                _, p_c, a_c = build_conv(c)
-                return lambda: a_c(sample, p_c)[0]
-
-            kcfg = _resolve_cfg(
-                name,
-                lambda hp=hp, wp=wp, c_pad=c_pad: autotune_mod.conv_key(
-                    hp, wp, c_pad, kh, kw, cout, stride, layer_sched,
-                    method, batch=batch, epilogue=not last, sparsity=True),
-                lambda hp=hp, wp=wp, c_pad=c_pad: autotune_mod.conv_candidates(
-                    hp, wp, c_pad, kh, kw, cout, layer_sched, method,
-                    interpret=interp, act_dtypes=("u8",)),
-                _build_thunk)
-            cop, p, apply = build_conv(kcfg)
-
+            h, w, cop, p, apply = conv_step(
+                name, qp["w_q"], qp["b_int"], qp["mult"], h=h, w=w,
+                c_pad=c_pad, in_bits=bits, stride=stride,
+                padding=cfg.get("padding", "VALID"))
             total_passes += bits * periods
             steps.append((apply, p))
             out_shape = (batch, h, w, cout)
@@ -784,22 +850,29 @@ def _compile_plan_impl(
 
         elif kind == "pool":
             window, pool_mode = cfg["window"], cfg.get("mode", "or")
-            h, w = h // window, w // window
+            stride = cfg.get("stride", window)
+            (a, b), (c, d) = conv_pads(cfg.get("padding", "VALID"), h, w,
+                                       window, window, stride)
+            pads = ((a, b), (c, d)) if a or b or c or d else "VALID"
+            h = (h + a + b - window) // stride + 1
+            w = (w + c + d - window) // stride + 1
+            geo = dict(window=window, stride=stride, padding=pads)
             if pool_mode == "avg":
                 # sum-pool widens the carry; stays packed while it fits a byte
                 bits = layers.sum_pool_bits(bits, window)
                 packed = bits <= 8
 
-                def apply(state, p, *, window=window, packed=packed):
-                    out = layers.q_avg_pool(state, window)
+                def apply(state, p, *, geo=geo, packed=packed):
+                    out = layers.q_avg_pool(state, **geo)
                     out = out.astype(jnp.uint8) if packed else out
                     return out, jnp.int32(0)
             elif pool_mode in ("or", "max"):
+                # zero pads are exact: levels are >= 0 and OR-neutral
                 fn = (layers.q_or_pool if pool_mode == "or"
                       else layers.q_max_pool)
 
-                def apply(state, p, *, fn=fn, window=window):
-                    return fn(state, window), jnp.int32(0)
+                def apply(state, p, *, fn=fn, geo=geo):
+                    return fn(state, **geo), jnp.int32(0)
             else:
                 raise ValueError(pool_mode)
             steps.append((apply, {}))
@@ -812,6 +885,70 @@ def _compile_plan_impl(
                 act_write_bytes=_elems(out_shape) * nbytes,
                 act_write_bytes_int32=_elems(out_shape) * 4,
             ))
+
+        elif kind == "block":
+            spec.validate_static(((kind, cfg),))
+            stride, proj = cfg.get("stride", 1), qp["proj"] is not None
+            cin, cout = qp["a"]["w_q"].shape[2:]
+            assert cin == c_real and bits == T, (cin, c_real, bits)
+            i = scope_counts[kind]
+            if cfg.get("proj", proj) != proj or not proj and (
+                    stride != 1 or cin != cout):
+                raise ValueError(
+                    f"block {i}: proj={cfg.get('proj')} with projection "
+                    f"weights {proj}, stride {stride}, {cin} -> {cout} "
+                    f"channels: an identity skip needs the output's shape")
+            res_scale = jnp.broadcast_to(
+                jnp.asarray(qp["res_scale"], jnp.int32).reshape(-1), (cout,))
+            b_join = jnp.asarray(qp["b"]["b_int"], jnp.int32)
+            h_b, w_b, cop_a, pa, apply_a = conv_step(
+                f"block{i}.a", qp["a"]["w_q"], qp["a"]["b_int"],
+                qp["a"]["mult"], h=h, w=w, c_pad=c_pad, in_bits=T,
+                stride=stride, padding=BLOCK_PAD)
+            p, apply_p = {"a": pa}, None
+            if proj:
+                # the projection's bias joins the epilogue's bias row
+                # (exact integers), so its kernel emits the bare int32
+                # accumulator and no add sits between the two kernels
+                *_, p["proj"], apply_p = conv_step(
+                    f"block{i}.proj", qp["proj"]["w_q"], None, None, h=h,
+                    w=w, c_pad=c_pad, in_bits=T, stride=stride,
+                    padding="VALID")
+                b_join = b_join + res_scale * jnp.asarray(
+                    qp["proj"]["b_int"], jnp.int32)
+            h, w, cop, p["b"], apply_b = conv_step(
+                f"block{i}.b", qp["b"]["w_q"], b_join, qp["b"]["mult"],
+                h=h_b, w=w_b, c_pad=cop_a, in_bits=T, stride=1,
+                padding=BLOCK_PAD, res_scale=res_scale,
+                skip_dtype=jnp.int32 if proj else jnp.uint8)
+
+            def apply(state, p, *, apply_a=apply_a, apply_p=apply_p,
+                      apply_b=apply_b):
+                a, skipped = apply_a(state, p["a"])
+                skip = state
+                if apply_p is not None:
+                    skip, more = apply_p(state, p["proj"])
+                    skipped = skipped + more
+                out, more = apply_b(a, p["b"], skip)
+                return out, skipped + more
+
+            convs = 3 if proj else 2
+            total_passes += bits * periods * convs
+            steps.append((apply, p))
+            out_shape = (batch, h, w, cout)
+            n_out = _elems(out_shape)
+            infos.append(PlanLayerInfo(
+                name=f"block{cin}->{cout}" + (f"/s{stride}" if stride > 1
+                                              else "")
+                + ("/proj" if proj else ""),
+                out_shape=out_shape,
+                out_dtype="uint8",
+                # conv_a's and the join's levels, the projection's int32
+                act_write_bytes=n_out * (2 + (4 if proj else 0)),
+                act_write_bytes_int32=n_out * 4 * convs,
+                skip_read_bytes=n_out * (4 if proj else 1),
+            ))
+            c_real, c_pad, bits = cout, cop, T
 
         elif kind == "flatten":
             steps.append((lambda state, p: (
@@ -931,6 +1068,7 @@ def _data_parallel_plan(qnet, input_shape, method, data_parallel, spec=None,
         out_shape=(l.out_shape[0] * data_parallel,) + l.out_shape[1:],
         act_write_bytes=l.act_write_bytes * data_parallel,
         act_write_bytes_int32=l.act_write_bytes_int32 * data_parallel,
+        skip_read_bytes=l.skip_read_bytes * data_parallel,
     ) for l in inner.layers]
     return CompiledPlan(
         input_shape=tuple(input_shape),
@@ -1291,12 +1429,10 @@ def memory_report(
         if kind == "conv":
             kh, kw, cin, cout = qp["w_q"].shape
             stride = cfg.get("stride", 1)
-            if cfg.get("padding", "VALID") == "SAME":
-                ho = -(-shape[0] // stride)
-                wo = -(-shape[1] // stride)
-            else:
-                ho = (shape[0] - kh) // stride + 1
-                wo = (shape[1] - kw) // stride + 1
+            (a, b), (c, d) = conv_pads(cfg.get("padding", "VALID"), shape[0],
+                                       shape[1], kh, kw, stride)
+            ho = (shape[0] + a + b - kh) // stride + 1
+            wo = (shape[1] + c + d - kw) // stride + 1
             shape = (ho, wo, cout)
             wbytes = math.ceil(kh * kw * cin * cout * qnet.weight_bits / 8)
             total_param_bytes += wbytes
@@ -1326,7 +1462,11 @@ def memory_report(
             max1d = max(max1d, fin, fout)
         elif kind == "pool":
             win = cfg["window"]
-            shape = (shape[0] // win, shape[1] // win, shape[2])
+            stride = cfg.get("stride", win)
+            (a, b), (c, d) = conv_pads(cfg.get("padding", "VALID"), shape[0],
+                                       shape[1], win, win, stride)
+            shape = ((shape[0] + a + b - win) // stride + 1,
+                     (shape[1] + c + d - win) // stride + 1, shape[2])
             layer_mems.append(LayerMem(
                 name=f"pool{win}", in_shape=in_shape, out_shape=shape,
                 act_bits=T, weight_bytes=0,
@@ -1337,6 +1477,10 @@ def memory_report(
         elif kind == "flatten":
             shape = (int(np.prod(shape)),)
             max1d = max(max1d, shape[0])
+        else:
+            raise ValueError(
+                f"memory_report models conv, pool, flatten and linear "
+                f"chains, not layer kind {kind!r}")
 
     buf2d = 2 * math.ceil(max2d * T / 8)          # ping + pong, T-bit packed
     buf1d = 2 * math.ceil(max1d * T / 8)

@@ -139,37 +139,52 @@ def snn_linear(planes: jax.Array, w_q: jax.Array, b_int: jax.Array) -> jax.Array
 # pooling --------------------------------------------------------------------
 
 
-def q_avg_pool(q_in: jax.Array, window: int) -> jax.Array:
+def _pool_window(window: int, stride=None, padding="VALID"):
+    """``reduce_window`` dimensions, strides and padding of an NHWC pool:
+    stride defaults to the window; ``padding`` is "VALID" or explicit
+    ``((lo, hi), (lo, hi))`` zero pads of H and W."""
+    s = window if stride is None else stride
+    if not isinstance(padding, str):
+        (a, b), (c, d) = padding
+        padding = ((0, 0), (a, b), (c, d), (0, 0))
+    return (1, window, window, 1), (1, s, s, 1), padding
+
+
+def q_avg_pool(q_in: jax.Array, window: int, stride=None,
+               padding="VALID") -> jax.Array:
     """Sum-pool accumulator (int32).  The window-size division is folded into
     the next layer's requant multiplier, as hardware would."""
     return lax.reduce_window(
         q_in.astype(jnp.int32),
         jnp.int32(0),
         lax.add,
-        (1, window, window, 1),
-        (1, window, window, 1),
-        "VALID",
+        *_pool_window(window, stride, padding),
     )
 
 
-def snn_avg_pool(planes: jax.Array, window: int) -> jax.Array:
+def snn_avg_pool(planes: jax.Array, window: int, stride=None,
+                 padding="VALID") -> jax.Array:
     """Spiking sum-pool: per-plane window sums, Horner over time."""
-    per_step = jax.vmap(lambda p: q_avg_pool(p, window))(planes)
+    per_step = jax.vmap(
+        lambda p: q_avg_pool(p, window, stride, padding))(planes)
     return neuron.radix_membrane(per_step)
 
 
-def q_max_pool(q_in: jax.Array, window: int) -> jax.Array:
+def q_max_pool(q_in: jax.Array, window: int, stride=None,
+               padding="VALID") -> jax.Array:
+    """Max pool of packed levels.  Zero padding equals -inf padding here:
+    levels are non-negative and every window of a padding below the
+    window size holds a real element."""
     return lax.reduce_window(
         q_in,
         jnp.zeros((), q_in.dtype),
         lax.max,
-        (1, window, window, 1),
-        (1, window, window, 1),
-        "VALID",
+        *_pool_window(window, stride, padding),
     )
 
 
-def q_or_pool(q_in: jax.Array, window: int) -> jax.Array:
+def q_or_pool(q_in: jax.Array, window: int, stride=None,
+              padding="VALID") -> jax.Array:
     """Bitwise-OR pooling of packed radix levels.
 
     The paper's pooling unit has *no output logic* (no requantizer): it pools
@@ -182,19 +197,18 @@ def q_or_pool(q_in: jax.Array, window: int) -> jax.Array:
         q_in.astype(jnp.int32),
         jnp.int32(0),
         lax.bitwise_or,
-        (1, window, window, 1),
-        (1, window, window, 1),
-        "VALID",
+        *_pool_window(window, stride, padding),
     ).astype(q_in.dtype)
 
 
-def snn_or_pool(planes: jax.Array, window: int) -> jax.Array:
+def snn_or_pool(planes: jax.Array, window: int, stride=None,
+                padding="VALID") -> jax.Array:
     """Per-plane OR pooling (binary max) — the spiking twin of ``q_or_pool``.
 
     Returns pooled spike planes (T, N, H', W', C); no Horner/requant stage,
     matching the paper's pooling unit.
     """
-    return jax.vmap(lambda p: q_max_pool(p, window))(planes)
+    return jax.vmap(lambda p: q_max_pool(p, window, stride, padding))(planes)
 
 
 def snn_max_pool(planes: jax.Array, window: int) -> jax.Array:

@@ -207,15 +207,18 @@ def conv_key(
     h: int, w: int, cin: int, kh: int, kw: int, cout: int, stride: int,
     schedule, dataflow: str,
     *, batch: int, epilogue: bool, sparsity: bool,
-    backend: Optional[str] = None,
+    backend: Optional[str] = None, joined: bool = False,
 ) -> tuple:
-    """Tuning-table key for a conv problem (same aliasing rules)."""
+    """Tuning-table key for a conv problem (same aliasing rules).  A
+    residual join (``joined``, a skip operand read in the epilogue) is a
+    problem of its own; other keys are as they always were."""
     bits, periods, grid = _schedule_fields(schedule)
     backend = backend or jax.default_backend()
-    return ("conv", backend, int(batch), int(h), int(w), int(cin), int(kh),
-            int(kw), int(cout), int(stride), bits, periods,
-            grid if epilogue else "raw", str(dataflow), bool(epilogue),
-            bool(sparsity))
+    key = ("conv", backend, int(batch), int(h), int(w), int(cin), int(kh),
+           int(kw), int(cout), int(stride), bits, periods,
+           grid if epilogue else "raw", str(dataflow), bool(epilogue),
+           bool(sparsity))
+    return key + ("joined",) if joined else key
 
 
 def attn_key(

@@ -248,10 +248,11 @@ def _conv_lowered(p, w, stride, mxu_dtype, acc_dtype="int32", a_bits=None):
     jax.jit,
     static_argnames=("num_steps", "method", "stride", "periods", "mxu_dtype",
                      "out_level", "out_grid", "acc_dtype"))
-def _xla_conv2d(x_q, w_q, bias, mult, occ, *, num_steps, method, stride=1,
-                periods=1, mxu_dtype="int8", out_level=None,
-                out_grid="dense", acc_dtype="int32"):
-    """Jitted XLA twin of ``radix_conv2d_pallas`` (VALID, pre-padded)."""
+def _xla_conv2d(x_q, w_q, bias, mult, occ, residual=None, res_scale=None,
+                *, num_steps, method, stride=1, periods=1, mxu_dtype="int8",
+                out_level=None, out_grid="dense", acc_dtype="int32"):
+    """Jitted XLA twin of ``radix_conv2d_pallas`` (VALID, pre-padded), and
+    with ``residual``/``res_scale`` of ``radix_conv2d_res_pallas``."""
     # same operand-lowering contract as ``_xla_matmul``: ``_conv_lowered``
     # casts per ``mxu_dtype``; only the bit algebra needs integer views
     w = w_q
@@ -285,6 +286,8 @@ def _xla_conv2d(x_q, w_q, bias, mult, occ, *, num_steps, method, stride=1,
             acc = acc // periods
     if mult is None:
         return acc
+    if residual is not None:
+        acc = acc + res_scale * residual.astype(jnp.int32)
     q = jnp.floor((acc + bias).astype(jnp.float32) * mult)
     return _project_levels(q, out_level=out_level, out_grid=out_grid)
 
@@ -483,8 +486,9 @@ def radix_conv2d(
     ``num_steps`` may be a bare T or a kernels-capable ``EncodingSpec``
     (whose packed bit count, period-repeat schedule and epilogue output
     grid are honored).  SAME padding is pre-padded (XLA-exact pads for
-    any stride); stride > 1 subsamples *inside* the kernel grid — only
-    the h_out x w_out surviving outputs are ever computed.  ``mult``
+    any stride); stride > 1 is folded into channels before the Pallas
+    kernel (``radix_conv.fold_stride_input``), so only the h_out x w_out
+    surviving outputs are ever computed.  ``mult``
     turns on the fused output-logic epilogue (packed uint8 levels out);
     ``sparsity=True`` runs the plane-occupancy prepass (empty planes
     skipped/masked in-kernel, bit-exact).  ``autotune=True`` times the

@@ -361,6 +361,12 @@ def hw_arch_from_qnet(qnet) -> list:
     """
     arch = []
     for (kind, cfg), ql in zip(qnet.static, qnet.qlayers):
+        if cfg.get("padding", "VALID") not in ("SAME", "VALID") or (
+                kind == "pool"
+                and cfg.get("stride", cfg["window"]) != cfg["window"]):
+            # the hardware model has no explicit pads nor strided pools
+            raise ValueError(
+                f"hardware model cannot cost {kind} geometry {cfg}")
         if kind == "conv":
             kh, _, _, cout = (int(d) for d in ql["w_q"].shape)
             arch.append(("conv", dict(
