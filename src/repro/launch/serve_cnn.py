@@ -51,8 +51,10 @@ import argparse
 import dataclasses
 import importlib
 import math
+import os
 import sys
 import time
+from concurrent import futures
 from typing import Callable, List, Optional, Sequence, Tuple
 
 import jax
@@ -76,6 +78,47 @@ __all__ = [
     "run_request_stream",
     "main",
 ]
+
+
+# A staged group is copied in parts of at least PART_BYTES on up to POOL
+# threads (numpy releases the GIL while it copies); a group under two
+# parts' bytes is copied on the calling thread (docs/serving.md §8).
+PART_BYTES = 4 << 20
+POOL = min(8, len(os.sched_getaffinity(0)))
+_copy_pool: Optional[futures.ThreadPoolExecutor] = None
+
+
+def _assemble(reqs: Sequence[np.ndarray], batch: np.ndarray) -> int:
+    """Copy ``reqs`` one after another along the batch axis into
+    ``batch``; the number of parts the copy was split into.
+
+    The parts split the item axis that is outermost in ``batch``'s
+    memory order (H for C-ordered and for batch-minor batches), never
+    the batch axis, so each part copies a slice of every request (into
+    one contiguous range of a batch-minor batch).  Every part has
+    finished when this returns, and the first part to fail raises."""
+    axes = [a for a in range(1, batch.ndim) if batch.shape[a] > 1]
+    axis = max(axes, key=lambda a: abs(batch.strides[a]), default=0)
+    parts = min(POOL, batch.nbytes // PART_BYTES,
+                batch.shape[axis]) if axis else 1
+    if parts <= 1:
+        np.concatenate(reqs, axis=0, out=batch)
+        return 1
+    cuts = [batch.shape[axis] * j // parts for j in range(parts + 1)]
+
+    def part(j: int) -> None:
+        at = (slice(None),) * axis + (slice(cuts[j], cuts[j + 1]),)
+        np.concatenate([x[at] for x in reqs], axis=0, out=batch[at])
+
+    global _copy_pool
+    if _copy_pool is None:
+        _copy_pool = futures.ThreadPoolExecutor(
+            POOL, thread_name_prefix="assemble")
+    running = [_copy_pool.submit(part, j) for j in range(parts)]
+    futures.wait(running)
+    for f in running:
+        f.result()
+    return parts
 
 
 # CLI name -> spec constructor; phase is the only one with an extra knob
@@ -333,10 +376,15 @@ class MicroBatchQueue:
     C-ordered requests, batch-minor for slices of an array that
     ``np.asarray`` made from a TPU array.  A single request goes to the
     server as it came; a larger group is concatenated into a new array.
-    The buffer's contract:
+    A staged copy of at least two ``PART_BYTES`` runs in parts on a
+    thread pool (:func:`_assemble`; ``split_assemblies`` counts those
+    groups).  The buffer's contract:
 
     * it is rewritten only after the previous ``server.infer`` on it has
       returned through ``jax.block_until_ready``;
+    * every part of a split copy has finished before ``server.infer``
+      is called; a part that raises is a fault of the group, as an
+      infer fault is;
     * after any infer fault the queue drops it and allocates another:
       the failed call may still read it (a transfer in flight, or a
       zero-copy alias on CPU), and the bisection and retry sub-flushes
@@ -397,6 +445,7 @@ class MicroBatchQueue:
         self.flushes = 0          # successful infer flushes (incl. halves)
         self._staging: Optional[np.ndarray] = None
         self.staging_allocs = 0   # staging buffers allocated for assembly
+        self.split_assemblies = 0  # staged groups copied in several parts
         # ids for the trace spans; the last flush id is the running flush's
         self._tickets = 0
         self._flush_ids = 0
@@ -552,31 +601,38 @@ class MicroBatchQueue:
 
     def _run_group(self, group) -> bool:
         """One batched infer over ``group``; on failure, bisect (multi-
-        ticket) or retry-then-quarantine (single ticket).  Returns True
-        if any infer attempt in the subtree faulted — the *caller*
+        ticket) or retry-then-quarantine (single ticket); a failed copy
+        into the staging buffer is a fault like a failed infer.  Returns
+        True if any attempt in the subtree faulted — the *caller*
         (``flush``) records at most one health failure per top-level
         group, not one per bisection level or retry attempt."""
         rows = sum(x.shape[0] for x, _ in group)
         staged = len(group) > 1 and rows <= self.max_batch
-        with span("queue.assemble", flush=self._flush_ids,
-                  bytes=sum(x.nbytes for x, _ in group), staged=int(staged)):
-            if len(group) == 1:
-                batch = group[0][0]
-            else:
-                batch = np.concatenate(
-                    [x for x, _ in group], axis=0,
-                    out=self._staging_batch(group, rows) if staged else None)
         if self.health.degraded:
             self.counters.degraded_flushes += 1
-        t0 = self.clock()
         try:
+            with span("queue.assemble", flush=self._flush_ids,
+                      bytes=sum(x.nbytes for x, _ in group),
+                      staged=int(staged)) as sp:
+                parts = 0
+                if len(group) == 1:
+                    batch = group[0][0]
+                elif staged:
+                    batch = self._staging_batch(group, rows)
+                    parts = _assemble([x for x, _ in group], batch)
+                    self.split_assemblies += parts > 1
+                else:
+                    batch = np.concatenate([x for x, _ in group], axis=0)
+                sp.set_metadata(parts=parts)
+            t0 = self.clock()
             with span("queue.infer", flush=self._flush_ids,
                       images=batch.shape[0]):
                 logits = self.server.infer(batch)
                 jax.block_until_ready(logits)
         except Exception as err:
             # the failed call may still read the buffer (a transfer in
-            # flight, or a zero-copy alias on CPU): never write it again
+            # flight, or a zero-copy alias on CPU): never write it again;
+            # a failed copy drops it too, as any fault of the group does
             self._staging = None
             if len(group) > 1:
                 # bisecting quarantine: O(log n) re-flushes isolate one

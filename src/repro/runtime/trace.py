@@ -24,12 +24,14 @@ __all__ = ["SPANS", "span", "read"]
 # span name -> the arguments it carries, along the served path
 # MicroBatchQueue -> CNNServer / api.Executable -> PlanCache -> CompiledPlan;
 # a flush's ``trigger`` is "full", "timeout", "backpressure" or "explicit";
-# ``staged`` is 1 when a group went into the queue's kept staging buffer
+# ``staged`` is 1 when a group went into the queue's kept staging buffer,
+# and ``parts`` is the number of parts that copy was split into (1: copied
+# on the calling thread; 0: not staged)
 SPANS = {
     "queue.submit": ("ticket", "images"),
     "queue.flush": ("flush", "tickets", "first_ticket", "last_ticket",
                     "images", "trigger"),
-    "queue.assemble": ("flush", "bytes", "staged"),
+    "queue.assemble": ("flush", "bytes", "staged", "parts"),
     "queue.infer": ("flush", "images"),
     "queue.resolve": ("flush", "tickets"),
     "exe.to_device": ("images", "bytes"),

@@ -8,6 +8,7 @@ emits a well-formed BENCH_serve.json.
 """
 
 import json
+from concurrent.futures import ThreadPoolExecutor
 
 import jax.numpy as jnp
 import numpy as np
@@ -235,6 +236,110 @@ def test_fault_drops_the_staging_buffer(server):
     staged = [b for b in spy.batches if b.base is not None]
     assert staged[1].base is first                # the failed root call
     assert all(b.base is not first for b in staged[2:])
+
+
+# ---------------------------------------------------------------------------
+# The staged copy split into parts on a thread pool.
+# ---------------------------------------------------------------------------
+
+
+def _split_queue(server, monkeypatch, part_bytes):
+    """A queue of 8 images whose staged copies split into parts of
+    ``part_bytes``, on up to 8 threads; the spy server it serves, and
+    the parts of each ``_assemble`` call."""
+    monkeypatch.setattr(serve_cnn, "PART_BYTES", part_bytes)
+    monkeypatch.setattr(serve_cnn, "POOL", 8)
+    parts = []
+    assemble = serve_cnn._assemble
+
+    def counted(reqs, batch):
+        parts.append(assemble(reqs, batch))
+        return parts[-1]
+
+    monkeypatch.setattr(serve_cnn, "_assemble", counted)
+    spy = SpyServer(server)
+    return serve_cnn.MicroBatchQueue(spy, max_batch=8, timeout_s=1e9), \
+        spy, parts
+
+
+# ``layout``: the pool's axes in memory order, outermost first
+@pytest.mark.parametrize("layout,sizes,part_bytes,want_parts", [
+    ((1, 2, 3, 0), (3, 1, 4), 10_000, 3),
+    ((1, 2, 0, 3), (5, 3), 4_096, 8),
+    ((0, 1, 2, 3), (2, 2, 4), 16_384, 2),
+    (None, (1,) * 8, 6_000, 5),
+], ids=["batch-minor", "batch-middle", "c-order", "single-images"])
+def test_split_copy_matches_a_new_concatenation(server, monkeypatch, layout,
+                                                sizes, part_bytes, want_parts):
+    """A staged group of 32 KiB with parts of ``part_bytes`` is copied in
+    ``want_parts`` parts along H (uneven ones included): the batch is
+    bit-equal to a new concatenation, laid out as it, and the logits
+    are bit-equal to the server's on it."""
+    q, spy, parts = _split_queue(server, monkeypatch, part_bytes)
+    if layout is None:
+        reqs = [_req(server, 1) for _ in sizes]
+    else:
+        stored = np.ascontiguousarray(_req(server, 12).transpose(layout))
+        pool = stored.transpose(np.argsort(layout))
+        offs = np.cumsum((0,) + sizes)
+        reqs = [pool[a + 1:b + 1] for a, b in zip(offs[:-1], offs[1:])]
+    tickets = [q.submit(r) for r in reqs]            # 8 rows -> flush
+    assert all(t.ok for t in tickets)
+    assert parts == [want_parts] and q.split_assemblies == 1
+    (batch,) = spy.batches
+    want = np.concatenate(reqs)
+    assert batch.base is q._staging and q.staging_allocs == 1
+    assert batch.strides == want.strides
+    np.testing.assert_array_equal(batch, want)
+    for t, w in zip(tickets, _fresh_logits(server, reqs)):
+        np.testing.assert_array_equal(np.asarray(t.result), w)
+
+
+def test_failed_part_drops_the_staging_buffer(server, monkeypatch):
+    """A part of a split copy raises: the group is bisected as after an
+    infer fault, every ticket resolves bit-exact from a new buffer, and
+    the half-written one is never given to the server."""
+    q, spy, parts = _split_queue(server, monkeypatch, 8_192)
+
+    class FailingPool:
+        """A real pool whose second part of the first copy raises."""
+
+        def __init__(self):
+            self.pool = ThreadPoolExecutor(2)
+            self.parts = 0
+
+        def submit(self, fn, j):
+            self.parts += 1
+            if self.parts == 2:
+                def fn(_j):
+                    raise RuntimeError("part failed")
+            return self.pool.submit(fn, j)
+
+    failing = FailingPool()
+    monkeypatch.setattr(serve_cnn, "_copy_pool", failing)
+    reqs = [_req(server, n) for n in (2, 2, 1, 3)]
+    tickets = [q.submit(r) for r in reqs]            # 8 rows -> flush
+    assert all(t.ok for t in tickets)
+    for t, w in zip(tickets, _fresh_logits(server, reqs)):
+        np.testing.assert_array_equal(np.asarray(t.result), w)
+    # the root's copy failed in 4 parts; the halves (16 KiB) split in 2
+    assert parts == [2, 2] and q.split_assemblies == 2
+    assert q.staging_allocs == 2 and len(spy.batches) == 2
+    assert all(b.base is q._staging for b in spy.batches)
+    failing.pool.shutdown()
+
+
+def test_small_staged_group_copies_inline(server, monkeypatch):
+    """A staged group under two parts' bytes is copied on the calling
+    thread, in one part, and starts no thread pool."""
+    monkeypatch.setattr(serve_cnn, "_copy_pool", None)
+    q, spy, parts = _split_queue(server, monkeypatch, serve_cnn.PART_BYTES)
+    reqs = [_req(server, n) for n in (3, 5)]
+    tickets = [q.submit(r) for r in reqs]
+    assert all(t.ok for t in tickets)
+    assert parts == [1] and q.split_assemblies == 0
+    assert serve_cnn._copy_pool is None
+    np.testing.assert_array_equal(spy.batches[0], np.concatenate(reqs))
 
 
 # ---------------------------------------------------------------------------

@@ -124,24 +124,34 @@ def test_spans_follow_the_served_path(server, tmp_path):
     assert _inside(pad, infers[1])
 
 
-def test_assemble_span_says_whether_the_group_was_staged(server, tmp_path):
+def test_assemble_span_says_whether_the_group_was_staged(server, tmp_path,
+                                                         monkeypatch):
     """``staged`` is 1 for a multi-request group that fits max_batch and
-    0 for one wider than it, which is concatenated into a new array."""
+    0 for one wider than it, which is concatenated into a new array.
+    ``parts`` is 1 for a staged group copied on the calling thread, the
+    number of parts for one copied on the thread pool (here with parts
+    of 8 KiB), and 0 for a group that was not staged."""
     rng = np.random.default_rng(5)
     q = serve_cnn.MicroBatchQueue(server, max_batch=MAX_BATCH,
                                   timeout_s=1e9, clock=FakeClock())
+    monkeypatch.setattr(serve_cnn, "POOL", 8)
     jax.profiler.start_trace(str(tmp_path))
     try:
-        for sizes in ((3, 5), (4, 6)):            # 8 rows, then 10 rows
+        for sizes in ((3, 5), (4, 6), (2, 6)):    # 8, 10, then 8 rows
+            if sizes == (2, 6):
+                monkeypatch.setattr(serve_cnn, "PART_BYTES", 8 << 10)
             tickets = [q.submit(rng.uniform(0, 1, (n,) + server.item_shape)
                                 .astype(np.float32)) for n in sizes]
             assert all(t.ok for t in tickets)
+        q.submit(np.zeros((3,) + server.item_shape, np.float32))
+        q.flush()                                 # a lone request
     finally:
         jax.profiler.stop_trace()
     assembles = [s for s in trace.read(tmp_path) if s[0] == "queue.assemble"]
     assert [(s[3]["staged"], s[3]["flush"]) for s in assembles] == [
-        (1, 1), (0, 2)]
-    assert q.staging_allocs == 1
+        (1, 1), (0, 2), (1, 3), (0, 4)]
+    assert [s[3]["parts"] for s in assembles] == [1, 0, 4, 0]
+    assert q.staging_allocs == 1 and q.split_assemblies == 1
 
 
 def test_no_session_same_logits_and_nothing_recorded(server, tmp_path):
